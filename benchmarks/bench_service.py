@@ -111,7 +111,7 @@ def run(quick: bool = False):
     for s_count in shard_counts:
         svc = KVService(s_count, structure="hashmap",
                         n_buckets=-(-2 * spec.n_keys // s_count),
-                        round_cap=round_cap)
+                        round_cap=round_cap, use_kernel=False)
         row = _run_service(svc, streams, load)
         ops_per_round[s_count] = row["ops_per_step"]
         us_per_call[s_count] = row["dt"] / row["n_ops"] * 1e6
@@ -147,7 +147,7 @@ def run(quick: bool = False):
     for c in ((2,) if quick else (2, 16)):
         svc = KVService(4, structure="hashmap",
                         n_buckets=-(-2 * spec.n_keys // 4),
-                        round_cap=round_cap)
+                        round_cap=round_cap, use_kernel=False)
         row = _run_service(svc, client_streams(spec, c), load)
         _emit_kv(f"service_kv_S4_c{c}_zipf{spec.alpha:g}", row)
 
@@ -158,7 +158,7 @@ def run(quick: bool = False):
     tsvc = KVService(2, structure="bztree", leaf_cap=4,
                      root_cap=max(4, t_spec.n_keys // 2),
                      n_regions=max(6, t_spec.n_keys // 2 + 2),
-                     round_cap=round_cap)
+                     round_cap=round_cap, use_kernel=False)
     row = _run_service(tsvc, client_streams(t_spec, n_clients),
                        load_phase(t_spec))
     splits = sum(t.splits for t in tsvc.structs)

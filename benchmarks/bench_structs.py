@@ -112,7 +112,7 @@ def run(quick: bool = False):
 
     # -- free-list allocator over reserve_slots -------------------------------
     n_slots = 64 if quick else 256
-    fl = FreeListAllocator(n_slots)
+    fl = FreeListAllocator(n_slots, use_kernel=False)
     t0 = time.time()
     grants = fl.alloc([4] * (n_slots // 8))
     dt = time.time() - t0

@@ -11,6 +11,8 @@ themselves) — plus an ``SLO_<section>.json`` burn-rate verdict: the
 section's queued :func:`benchmarks.common.slo_observe` observations
 replayed through the specs in :mod:`benchmarks.slo_specs` (always at
 least one evaluated spec, via the per-section ``elapsed_s`` ceiling).
+JAX's persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, else to ``.jax_cache/`` at the repository root.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only SECTION]
                                             [--json-dir DIR | --no-json]
@@ -20,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import platform
 import sys
@@ -76,6 +79,10 @@ def main() -> None:
                     help="skip the per-section TRACE_<section>.json")
     args = ap.parse_args()
 
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):  # else JAX reads it
+        jax.config.update("jax_compilation_cache_dir", str(
+            pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"))
     from . import (bench_blocks, bench_chaos, bench_ckpt, bench_diff,
                    bench_durable, bench_elastic, bench_kernels,
                    bench_service, bench_skew, bench_structs, bench_threads,

@@ -49,7 +49,7 @@ def drive(svc):
 
 print("=== 1. 8 clients on a 4-shard service (stacked kernel rounds) ===")
 svc4 = KVService(4, structure="hashmap", n_buckets=2 * SPEC.n_keys,
-                 round_cap=4)
+                 round_cap=4, use_kernel=False)
 st4 = drive(svc4)
 print("  " + st4.summary().replace("\n", "\n  "))
 print(f"  executor: {type(svc4.executor).__name__} "
@@ -57,7 +57,7 @@ print(f"  executor: {type(svc4.executor).__name__} "
 
 print("\n=== 2. same traffic, one shard: round throughput drops ===")
 svc1 = KVService(1, structure="hashmap", n_buckets=8 * SPEC.n_keys,
-                 round_cap=4)
+                 round_cap=4, use_kernel=False)
 st1 = drive(svc1)
 print(f"  S=4: {st4.ops_per_step:.1f} ops/round-wave   "
       f"S=1: {st1.ops_per_step:.1f} ops/round-wave")

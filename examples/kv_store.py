@@ -52,7 +52,7 @@ print(f"  {len(before)} live keys before crash == {len(after)} after "
 print("\n=== 3. three-substrate differential on a conflict workload ===")
 ops = compile_workload(dataclasses.replace(
     SPEC, n_ops=32, n_keys=8, read=0.2, update=0.2, insert=0.5, delete=0.1))
-rep = run_struct_differential(ops, n_buckets=8)
+rep = run_struct_differential(ops, n_buckets=8, use_kernel=False)
 print("  " + rep.summary().replace("\n", "\n  "))
 assert rep.agree and rep.sim_rounds_checked >= 1
 

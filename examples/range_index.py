@@ -59,7 +59,8 @@ print("\n=== 4. three-substrate differential on a splitting workload ===")
 ops = load_phase(SPEC) + compile_workload(
     dataclasses.replace(SPEC, n_ops=32, scan=0.25, insert=0.45, read=0.2,
                         update=0.1))
-rep = run_struct_differential(ops, structure="bztree", **SHAPE)
+rep = run_struct_differential(ops, structure="bztree", use_kernel=False,
+                              **SHAPE)
 print("  " + rep.summary().replace("\n", "\n  "))
 assert rep.agree and rep.sim_rounds_checked >= 1
 print("range_index OK")

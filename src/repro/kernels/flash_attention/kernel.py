@@ -14,11 +14,14 @@ tests/test_kernels.py.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.platform import pallas_interpret
 
 NEG_INF = -2.0 ** 20
 
@@ -73,9 +76,12 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 def flash_attention_flat(q, k, v, q_pos, k_pos, *, scale: float,
                          causal: bool, window: int, attn_cap: float,
                          g: int, tq: int = 128, tk: int = 128,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """q: [H, Sq, hd] (H = B*KV*G flattened), k/v: [HK, Sk, hd] with
-    HK = B*KV; q head h reads kv head h // g."""
+    HK = B*KV; q head h reads kv head h // g.  ``interpret=None`` lets
+    :func:`repro.platform.pallas_interpret` decide."""
+    if interpret is None:
+        interpret = pallas_interpret()
     H, Sq, hd = q.shape
     HK, Sk, _ = k.shape
     TQ, TK = min(tq, Sq), min(tk, Sk)

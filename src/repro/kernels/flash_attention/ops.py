@@ -4,9 +4,6 @@ from __future__ import annotations
 
 from .kernel import flash_attention_flat
 
-# interpret mode on this CPU container; flip to False on real TPU
-INTERPRET = True
-
 
 def flash_attention(q, k, v, q_pos, k_pos, *, causal, window, attn_cap,
                     scale, tq: int = 128, tk: int = 128):
@@ -17,6 +14,5 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal, window, attn_cap,
     vf = v.reshape(B * KV, Sk, hd)
     out = flash_attention_flat(
         qf, kf, vf, q_pos, k_pos, scale=float(scale), causal=bool(causal),
-        window=int(window), attn_cap=float(attn_cap), g=G, tq=tq, tk=tk,
-        interpret=INTERPRET)
+        window=int(window), attn_cap=float(attn_cap), g=G, tq=tq, tk=tk)
     return out.reshape(B, KV, G, Sq, hd)

@@ -1,13 +1,16 @@
 """Jit'd public op: batched MwCAS apply against a word table.
 
 Gather + scatter stay in XLA (they are memory-layout operations XLA
-already emits optimally); the Pallas kernel resolves conflicts.  On this
-CPU container the kernel runs in interpret mode; on TPU set
-``interpret=False``.
+already emits optimally); the Pallas kernel resolves conflicts.
+``interpret=None`` leaves the kernel mode to the platform
+(:func:`repro.platform.pallas_interpret`: compiled on a TPU, interpreted
+elsewhere); ``use_kernel=False`` is the pure-jnp oracle, for tests that
+need a reference.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +21,7 @@ from .kernel import pmwcas_success_pallas
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def pmwcas_apply(words, addr, exp, des, *, use_kernel: bool = True,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """words: uint32[W]; addr int32[B,K] (<0 pad); exp/des uint32[B,K].
     Returns (new_words, success[B])."""
     cur = words[jnp.maximum(addr, 0)]
@@ -37,7 +40,7 @@ def pmwcas_apply(words, addr, exp, des, *, use_kernel: bool = True,
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"),
                    donate_argnums=(0,))
 def pmwcas_apply_stacked(words, addr, exp, des, *, use_kernel: bool = True,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """S shard rounds in ONE dispatch: vmap of :func:`pmwcas_apply`.
 
     words: uint32[S, W] stacked shard word tables; addr int32[S, B, K]
@@ -60,7 +63,7 @@ def pmwcas_apply_stacked(words, addr, exp, des, *, use_kernel: bool = True,
 
 
 def reserve_slots(free_mask, requests, *, use_kernel: bool = True,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """KV-cache slot reservation for the serving layer: request i atomically
     claims `requests[i]` slots (a K-word MwCAS on a free-bitmap word table).
 

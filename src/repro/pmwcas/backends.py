@@ -84,15 +84,17 @@ def _compiled_step(cfg: SimConfig):
 class KernelBackend:
     """Word table + the batched Pallas conflict-resolution kernel.
 
-    ``use_kernel=False`` routes verdicts through the pure-jnp oracle
-    (``kernels.pmwcas_apply.ref``) — bit-identical by test, useful when
-    Pallas interpret mode is too slow for a sweep.
+    The kernel runs compiled on a TPU and in interpret mode elsewhere
+    (:func:`repro.platform.pallas_interpret`).  ``use_kernel=False``
+    routes verdicts through the pure-jnp oracle
+    (``kernels.pmwcas_apply.ref``) — bit-identical by test, for tests that
+    need a reference or a fast CPU path.
     """
     name = "kernel"
 
     def __init__(self, n_words: Optional[int] = None,
                  values: Optional[Sequence[int]] = None, *,
-                 use_kernel: bool = True, interpret: bool = True):
+                 use_kernel: bool = True):
         import jax.numpy as jnp
         if values is not None:
             self._words = jnp.asarray(np.asarray(values, np.uint32))
@@ -101,7 +103,6 @@ class KernelBackend:
         else:
             raise ValueError("need n_words or values")
         self.use_kernel = use_kernel
-        self.interpret = interpret
 
     # -- Backend protocol ------------------------------------------------------
     def execute(self, ops: Sequence[MwCASOp],
@@ -112,8 +113,7 @@ class KernelBackend:
             addr, exp, des = ops_to_arrays(ops, k)
             new, success = pmwcas_apply(
                 self._words, jnp.asarray(addr), jnp.asarray(exp),
-                jnp.asarray(des), use_kernel=self.use_kernel,
-                interpret=self.interpret)
+                jnp.asarray(des), use_kernel=self.use_kernel)
             self._words = new
             return results_from_mask(ops, np.asarray(success), self.name)
 

@@ -75,15 +75,13 @@ def run_differential(ops: Sequence[MwCASOp],
                      initial_values: Sequence[int], *,
                      algorithm: Union[str, Algorithm] = OURS,
                      durable_root=None,
-                     use_kernel: bool = True,
-                     interpret: bool = True) -> DifferentialReport:
+                     use_kernel: bool = True) -> DifferentialReport:
     """Execute one batch on all three backends and compare outcomes."""
     initial = np.asarray(initial_values, np.uint32)
     n_words = len(initial)
     addrs = sorted({a for op in ops for a in op.addrs})
 
-    kernel = KernelBackend(values=initial, use_kernel=use_kernel,
-                           interpret=interpret)
+    kernel = KernelBackend(values=initial, use_kernel=use_kernel)
     sim = SimBackend(n_words, algorithm=algorithm, values=initial)
     durable = DurableBackend(durable_root)
     durable.seed({a: int(initial[a]) for a in addrs})

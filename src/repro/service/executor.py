@@ -179,7 +179,8 @@ class StackedKernelExecutor:
     temporary is donated to the dispatch (`pmwcas_apply_stacked`), and
     ``stats``/:class:`DispatchStats` counts traces vs cache hits plus
     the padding bytes bucketing ships — steady-state waves must be
-    all hits.
+    all hits.  ``shapes`` holds every ``(S, B, K, n_words, use_kernel)``
+    dispatched so far.
     """
 
     name = "stacked"
@@ -190,11 +191,11 @@ class StackedKernelExecutor:
         self.last_stacked = 0
         self.stacked_dispatches = 0
         self.stats = DispatchStats()
-        self._shapes: Set[Hashable] = set()     # mirror of XLA's trace cache
+        self.shapes: Set[Hashable] = set()      # mirror of XLA's trace cache
 
     @staticmethod
     def _group_key(backend: KernelBackend) -> Hashable:
-        return (backend.n_words, backend.use_kernel, backend.interpret)
+        return (backend.n_words, backend.use_kernel)
 
     def execute(self, backends: Sequence[Backend],
                 rounds: Dict[int, List[MwCASOp]]) -> Dict[int, List[bool]]:
@@ -219,7 +220,7 @@ class StackedKernelExecutor:
                 # a lone kernel shard gains nothing from stacking
                 rest[shards[0]] = rounds[shards[0]]
                 continue
-            n_words, use_kernel, interpret = key
+            n_words, use_kernel = key
             B = max(len(rounds[s]) for s in active)
             if self.round_cap and self.round_cap >= B:
                 B = self.round_cap
@@ -227,12 +228,12 @@ class StackedKernelExecutor:
                 B = 1 << (B - 1).bit_length()    # capless: pow2 bucket
             K = max(op.k for s in active for op in rounds[s])
             K = 1 << (K - 1).bit_length()        # next power of two
-            shape = (len(shards), B, K, n_words, use_kernel, interpret)
-            if shape in self._shapes:
+            shape = (len(shards), B, K, n_words, use_kernel)
+            if shape in self.shapes:
                 self.stats.hits += 1
                 traced = False
             else:
-                self._shapes.add(shape)
+                self.shapes.add(shape)
                 self.stats.traces += 1
                 traced = True
             addr = np.full((len(shards), B, K), -1, np.int32)
@@ -254,8 +255,7 @@ class StackedKernelExecutor:
                                    for s in shards])
                 new, success = pmwcas_apply_stacked(
                     words, jnp.asarray(addr), jnp.asarray(exp),
-                    jnp.asarray(des), use_kernel=use_kernel,
-                    interpret=interpret)
+                    jnp.asarray(des), use_kernel=use_kernel)
                 success = np.asarray(success)
             for i, s in enumerate(shards):
                 backends[s].set_word_table(new[i])

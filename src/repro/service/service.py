@@ -131,8 +131,7 @@ class KVService:
                  epoch_rounds: int = 1, checkpoint_every: int = 0,
                  wal_prune_every: int = 0,
                  migration_pool=None, migration_chunk: int = 8,
-                 use_kernel: bool = False, interpret: bool = True,
-                 executor=None):
+                 use_kernel: bool = True, executor=None):
         if n_shards < 1:
             raise ValueError("need at least one shard")
         if structure not in ("hashmap", "bztree"):
@@ -153,8 +152,7 @@ class KVService:
         self.checkpoint_every = max(0, int(checkpoint_every))
         self.backends = self._build_backends(
             backend, n_shards, words, durable_root, group_commit,
-            self.epoch_rounds, self.checkpoint_every,
-            use_kernel, interpret)
+            self.epoch_rounds, self.checkpoint_every, use_kernel)
         self.structs = [self._attach(b) for b in self.backends]
         # epoch ack gate (DESIGN.md Sec. 14): decisions made while ANY
         # durable shard has an open epoch are withheld here, in decide
@@ -194,7 +192,7 @@ class KVService:
     @staticmethod
     def _build_backends(spec, n_shards, words, durable_root, group_commit,
                         epoch_rounds, checkpoint_every,
-                        use_kernel, interpret) -> List[Backend]:
+                        use_kernel) -> List[Backend]:
         if isinstance(spec, (list, tuple)):
             if len(spec) != n_shards:
                 raise ValueError(f"{len(spec)} backends for {n_shards} "
@@ -203,8 +201,7 @@ class KVService:
         out = []
         for s in range(n_shards):
             if spec == "kernel":
-                kw = dict(n_words=words, use_kernel=use_kernel,
-                          interpret=interpret)
+                kw = dict(n_words=words, use_kernel=use_kernel)
             elif spec == "durable":
                 root = (None if durable_root is None
                         else pathlib.Path(durable_root) / f"shard{s}")

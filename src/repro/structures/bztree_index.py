@@ -185,9 +185,13 @@ class BzTreeIndex:
         self.pending_addr = base + 1
         self.region_base = base + 2
         self.n_regions = n_regions
+        # region grants run where the backend's verdicts run: the kernel
+        # for a kernel backend (unless it was built on the oracle), the
+        # jnp oracle beside a host-side durable or simulator backend
         self.allocator = FreeListAllocator(
             n_regions, region_base=self.region_base,
-            region_words=self.region_words)
+            region_words=self.region_words,
+            use_kernel=getattr(backend, "use_kernel", False))
         self.n_words = 2 + n_regions * self.region_words
         self.last_history: List[RoundTrace] = []
         # cumulative instrumentation (HashMap vocabulary + split counters)

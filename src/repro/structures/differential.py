@@ -156,8 +156,7 @@ def _replay_rounds_on_sim(history: List[RoundTrace],
 def run_struct_differential(kvops: Sequence[KVOp], n_buckets: int = 0, *,
                             structure: str = "hashmap",
                             algorithm: Union[str, Algorithm] = OURS,
-                            durable_root=None, use_kernel: bool = False,
-                            interpret: bool = True,
+                            durable_root=None, use_kernel: bool = True,
                             max_rounds: Optional[int] = None,
                             max_doublings: int = 0,
                             leaf_cap: int = 4, root_cap: int = 8,
@@ -193,8 +192,7 @@ def run_struct_differential(kvops: Sequence[KVOp], n_buckets: int = 0, *,
     else:
         raise ValueError(f"unknown structure {structure!r}; "
                          "expected 'hashmap' or 'bztree'")
-    kernel = KernelBackend(n_words=n_words, use_kernel=use_kernel,
-                           interpret=interpret)
+    kernel = KernelBackend(n_words=n_words, use_kernel=use_kernel)
     durable = DurableBackend(durable_root)
     maps = {"kernel": make(kernel), "durable": make(durable)}
 

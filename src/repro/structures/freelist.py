@@ -48,8 +48,7 @@ class OutOfRegions(RuntimeError):
 
 class FreeListAllocator:
     def __init__(self, n_slots: int, *, region_base: int = 0,
-                 region_words: int = 0, use_kernel: bool = False,
-                 interpret: bool = True):
+                 region_words: int = 0, use_kernel: bool = True):
         import jax.numpy as jnp
         if n_slots < 1:
             raise ValueError("need at least one slot")
@@ -57,7 +56,6 @@ class FreeListAllocator:
         self.region_base = region_base
         self.region_words = region_words
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self._mask = jnp.ones((n_slots,), jnp.uint32)
 
     # -- views -----------------------------------------------------------------
@@ -87,8 +85,7 @@ class FreeListAllocator:
         for i, c in enumerate(candidates):
             reqs[i, :len(c)] = sorted(c)
         new_mask, granted = reserve_slots(
-            self._mask, jnp.asarray(reqs), use_kernel=self.use_kernel,
-            interpret=self.interpret)
+            self._mask, jnp.asarray(reqs), use_kernel=self.use_kernel)
         self._mask = new_mask
         return [bool(g) for g in np.asarray(granted)]
 
@@ -162,8 +159,7 @@ class FreeListAllocator:
         des = np.ones_like(addr, dtype=np.uint32)      # back to free
         new_mask, success = pmwcas_apply(
             self._mask, jnp.asarray(addr), jnp.asarray(exp),
-            jnp.asarray(des), use_kernel=self.use_kernel,
-            interpret=self.interpret)
+            jnp.asarray(des), use_kernel=self.use_kernel)
         if not bool(np.asarray(success)[0]):
             raise DoubleFree(f"free() of already-free slot among {ids}")
         self._mask = new_mask

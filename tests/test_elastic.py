@@ -226,7 +226,8 @@ def test_elastic_differential_growth_rounds_zero_skips(tmp_path):
     kvops = [KVOp(INSERT, k, k * 2) for k in range(5, 100, 5)]
     kvops += [KVOp(UPDATE, 25, 7), KVOp(DELETE, 30), KVOp(READ, 25)]
     rep = run_struct_differential(kvops, n_buckets=4, max_doublings=3,
-                                  durable_root=tmp_path / "diff")
+                                  durable_root=tmp_path / "diff",
+                                  use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.sim_rounds_skipped == 0, rep.summary()
     assert rep.sim_rounds_checked > 5

@@ -270,7 +270,8 @@ def test_kvservice_steady_state_waves_never_retrace():
     """The acceptance counter: after warmup (load phase), a measurement
     window of same-bucket waves recompiles NOTHING — reset_stats zeroes
     the counters but keeps the trace cache warm."""
-    svc = KVService(4, structure="hashmap", n_buckets=32, round_cap=4)
+    svc = KVService(4, structure="hashmap", n_buckets=32, round_cap=4,
+                    use_kernel=False)
     svc.apply([KVOp(INSERT, k, k) for k in range(1, 33)])      # warmup
     svc.reset_stats()
     svc.apply([KVOp(UPDATE, k, k + 100) for k in range(1, 33)])
@@ -282,7 +283,8 @@ def test_kvservice_steady_state_waves_never_retrace():
 
 
 def test_serial_executor_counts_rounds():
-    svc = KVService(1, structure="hashmap", n_buckets=16, round_cap=4)
+    svc = KVService(1, structure="hashmap", n_buckets=16, round_cap=4,
+                    use_kernel=False)
     svc.apply([KVOp(INSERT, k, k) for k in range(1, 9)])
     d = svc.stats.dispatch
     assert d is not None and d.serial_rounds > 0 and d.dispatches == 0
@@ -350,5 +352,5 @@ def test_durability_stats_merge_and_row():
 
 
 def test_kvservice_durability_stats_none_for_kernel_shards():
-    svc = KVService(2, structure="hashmap", n_buckets=8)
+    svc = KVService(2, structure="hashmap", n_buckets=8, use_kernel=False)
     assert svc.durability_stats() is None

@@ -267,7 +267,7 @@ def _drive(svc, n=24, key0=1):
 
 
 def test_service_wall_clock_percentiles(tmp_path):
-    svc = KVService(2, structure="hashmap", n_buckets=64)
+    svc = KVService(2, structure="hashmap", n_buckets=64, use_kernel=False)
     _drive(svc)
     row = svc.stats.as_row()
     assert row["p99_latency_us"] >= row["p50_latency_us"] > 0
@@ -308,7 +308,7 @@ def test_reset_stats_zeroes_registry_window(tmp_path):
 
 
 def test_reset_stats_keeps_trace_cache_warm():
-    svc = KVService(2, structure="hashmap", n_buckets=64)
+    svc = KVService(2, structure="hashmap", n_buckets=64, use_kernel=False)
     _drive(svc)                        # warm-up: traces the shapes
     assert svc.stats.dispatch is not None
     svc.reset_stats()

@@ -130,7 +130,8 @@ def test_per_op_read_barrier_pays_redundant_fences_with_labels():
 # -- op lifecycle: op_id threading + latency partition -------------------------
 
 def test_op_lifecycle_instants_and_breakdown_identity():
-    svc = KVService(2, structure="hashmap", n_buckets=32, round_cap=2)
+    svc = KVService(2, structure="hashmap", n_buckets=32, round_cap=2,
+                    use_kernel=False)
     svc.apply([KVOp("insert", k, k) for k in range(1, 9)])
     svc.reset_stats()
     enable_tracing().clear()
@@ -188,7 +189,7 @@ def test_retry_waves_histogram_counts_split_retries():
     # (scheduling defers recompile for free) — a tiny-leaf BzTree under
     # an insert burst forces splits, so some op must retry its wave
     svc = KVService(1, structure="bztree", leaf_cap=4, root_cap=16,
-                    n_regions=24, round_cap=4)
+                    n_regions=24, round_cap=4, use_kernel=False)
     svc.reset_stats()
     for i in range(16):
         svc.submit(KVOp("insert", 10 + i, 1000 + i), client=i % 4)

@@ -382,7 +382,7 @@ def test_kvservice_matches_flat_hashmap_reference():
     spec = _spec()
     ops = load_phase(spec) + compile_workload(spec)
     svc = KVService(4, structure="hashmap", n_buckets=2 * spec.n_keys,
-                    round_cap=8)
+                    round_cap=8, use_kernel=False)
     got = svc.apply(ops)
     ref_map = HashMap(KernelBackend(n_words=16 * spec.n_keys,
                                     use_kernel=False), 8 * spec.n_keys)
@@ -398,7 +398,8 @@ def test_kvservice_many_clients_interleaved():
     spec = _spec(n_ops=64)
     streams = client_streams(spec, 8)
     assert len(streams) == 8 and all(len(s) == 8 for s in streams)
-    svc = KVService(4, structure="hashmap", n_buckets=64, round_cap=8)
+    svc = KVService(4, structure="hashmap", n_buckets=64, round_cap=8,
+                    use_kernel=False)
     futs = []
     for client, stream in enumerate(streams):
         futs += [svc.submit(op, client=client) for op in stream]
@@ -413,7 +414,8 @@ def test_kvservice_many_clients_interleaved():
 
 
 def test_kvservice_round_cap_bounds_occupancy():
-    svc = KVService(1, structure="hashmap", n_buckets=64, round_cap=2)
+    svc = KVService(1, structure="hashmap", n_buckets=64, round_cap=2,
+                    use_kernel=False)
     svc.apply([KVOp(INSERT, k, k) for k in range(1, 11)])
     s = svc.stats.shards[0]
     assert s.rounds >= 5 and s.overflows > 0
@@ -422,7 +424,7 @@ def test_kvservice_round_cap_bounds_occupancy():
 
 def test_kvservice_bztree_shards_split_and_gc():
     svc = KVService(2, structure="bztree", leaf_cap=2, root_cap=4,
-                    n_regions=6, round_cap=4)
+                    n_regions=6, round_cap=4, use_kernel=False)
     res = svc.apply([KVOp(INSERT, k, k) for k in range(1, 13)])
     assert all(r.status == OK for r in res)
     before = svc.check_integrity()
@@ -487,7 +489,8 @@ def test_kvservice_scan_covers_every_shard():
     for structure, kw in (("hashmap", dict(n_buckets=32)),
                           ("bztree", dict(leaf_cap=4, root_cap=8,
                                           n_regions=10))):
-        svc = KVService(4, structure=structure, round_cap=8, **kw)
+        svc = KVService(4, structure=structure, round_cap=8,
+                        use_kernel=False, **kw)
         svc.apply([KVOp(INSERT, k, k) for k in keys])
         (r,) = svc.apply([KVOp("scan", 1)])
         assert r.status == OK and r.value == len(keys), (structure, r)
@@ -499,7 +502,7 @@ def test_kvservice_region_exhaustion_is_counted():
     """The typed OutOfRegions reaches the service: exhaustion-FULL is
     distinguishable from root-FULL in the shard stats."""
     svc = KVService(1, structure="bztree", leaf_cap=2, root_cap=8,
-                    n_regions=2, round_cap=4)
+                    n_regions=2, round_cap=4, use_kernel=False)
     res = svc.apply([KVOp(INSERT, k, k) for k in range(1, 9)])
     assert FULL in {r.status for r in res}
     assert svc.stats.shards[0].out_of_regions >= 1
@@ -509,13 +512,13 @@ def test_kvservice_exhaustion_counts_attempts_not_queue_delay():
     # queue delay never exhausts: a tiny round cap forces long queues,
     # yet every op completes OK because it never loses a round
     svc = KVService(1, structure="hashmap", n_buckets=64, round_cap=1,
-                    max_op_rounds=1)
+                    max_op_rounds=1, use_kernel=False)
     res = svc.apply([KVOp(INSERT, k, k) for k in range(1, 13)])
     assert all(r.status == OK for r in res)
     # genuine retry churn does: with a zero attempt budget, the split
     # retry of a full-leaf insert exhausts instead of retrying
     tsvc = KVService(1, structure="bztree", leaf_cap=2, root_cap=4,
-                     n_regions=4, max_op_rounds=0)
+                     n_regions=4, max_op_rounds=0, use_kernel=False)
     res = tsvc.apply([KVOp(INSERT, k, k) for k in (1, 2, 3)])
     assert [r.status for r in res] == [OK, OK, "exhausted"]
 

@@ -15,10 +15,9 @@ from repro.parallel.sharding import ShardingRules
 
 
 def _mesh(multi_pod=False):
-    # AbstractMesh takes a tuple of (axis_name, size) pairs
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 def _check_spec_divides(shape, spec, mesh):

@@ -259,7 +259,7 @@ def test_struct_differential_workload(tmp_path):
     ops = ([KVOp(INSERT, k, 10 * k) for k in (3, 7, 11, 15)]     # same chain
            + [KVOp(INSERT, 3, 5), KVOp(INSERT, 21, 9)])
     rep = run_struct_differential(ops, n_buckets=4,
-                                  durable_root=tmp_path)
+                                  durable_root=tmp_path, use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.sim_rounds_checked >= 1
     assert rep.statuses["kernel"].count(OK) == 4
@@ -270,7 +270,7 @@ def test_struct_differential_mixed_mutations(tmp_path):
     ops = [KVOp(INSERT, 5, 100), KVOp(INSERT, 13, 200),
            KVOp(UPDATE, 5, 111), KVOp(DELETE, 13), KVOp(INSERT, 5, 1)]
     rep = run_struct_differential(ops, n_buckets=8,
-                                  durable_root=tmp_path)
+                                  durable_root=tmp_path, use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.items["kernel"] == rep.items["durable"]
 
@@ -284,7 +284,8 @@ def test_struct_differential_native_sim_no_shadow_skips(tmp_path):
     ops = ([KVOp(INSERT, k, (k << 8) | 1) for k in (2, 6, 10, 14)]
            + [KVOp(UPDATE, 2, 123456), KVOp(DELETE, 6),
               KVOp(INSERT, 18, 7), KVOp(DELETE, 10), KVOp(INSERT, 6, 999)])
-    rep = run_struct_differential(ops, n_buckets=8, durable_root=tmp_path)
+    rep = run_struct_differential(ops, n_buckets=8, durable_root=tmp_path,
+                                  use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.sim_rounds_checked >= 3
     assert rep.sim_rounds_skipped == 0, \
@@ -300,7 +301,7 @@ def test_tree_differential_native_sim_mixed_width_rounds(tmp_path):
     ops = load_phase(spec) + compile_workload(spec)
     rep = run_struct_differential(ops, structure="bztree", leaf_cap=2,
                                   root_cap=8, n_regions=10,
-                                  durable_root=tmp_path)
+                                  durable_root=tmp_path, use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.sim_rounds_checked >= 3
     assert rep.sim_rounds_skipped == 0
@@ -880,7 +881,7 @@ def test_tree_ycsb_differential(tmp_path, mix):
     ops = load_phase(spec) + compile_workload(spec)
     rep = run_struct_differential(ops, structure="bztree", leaf_cap=2,
                                   root_cap=8, n_regions=10,
-                                  durable_root=tmp_path)
+                                  durable_root=tmp_path, use_kernel=False)
     assert rep.agree, rep.summary()
     assert rep.sim_rounds_checked >= 1
     assert rep.items["kernel"] == rep.items["durable"]
