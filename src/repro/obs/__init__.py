@@ -42,14 +42,14 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from .provenance import (current_flush_reason, flush_reason, record_fence)
 from .slo import SloEngine, SloSpec, validate_slo_report
 from .trace import (NULL_SPAN, SpanTracer, disable_tracing,
-                    enable_tracing, get_tracer, instant, span,
-                    tracing_enabled)
+                    enable_tracing, get_tracer, instant, op_tracing,
+                    span, tracing_enabled)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "get_registry", "reset_metrics",
     "SpanTracer", "NULL_SPAN", "span", "instant", "get_tracer",
-    "enable_tracing", "disable_tracing", "tracing_enabled",
+    "enable_tracing", "disable_tracing", "tracing_enabled", "op_tracing",
     "chrome_trace", "export_chrome_trace", "export_jsonl",
     "validate_chrome_trace", "span_tree",
     "flush_reason", "current_flush_reason", "record_fence",

@@ -12,7 +12,15 @@ One tracer, two states:
   (``ph: "X"``, microsecond ``ts``/``dur``) into a thread-safe ring
   buffer.  Nesting is tracked per thread, so each event knows its
   parent span by name; Perfetto/chrome://tracing reconstruct the same
-  nesting from the timestamps alone.
+  nesting from the timestamps alone.  Each span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so under an active
+  ``jax.profiler`` trace it lands on the host plane of the
+  ``.xplane.pb``, on the device ops' clock (instants are not mirrored).
+
+Per-op lifecycle instants (``op.submit``/``op.requeue``/``op.ack_held``/
+``op.complete``) fire once per op, so they are opt-in on top:
+``enable_tracing(ops=True)``; :func:`op_tracing` is the check the
+submit and completion paths make.
 
 The buffer is a bounded deque (``capacity`` events): a chaos soak run
 cannot grow memory without bound — old events fall off the front and
@@ -35,6 +43,7 @@ class _NullSpan:
     """The disabled-path singleton: every method is a no-op."""
 
     __slots__ = ()
+    dur_ns = 0
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -50,9 +59,11 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span (enabled tracer only); records on ``__exit__``."""
+    """One live span (enabled tracer only); records on ``__exit__``.
+    ``dur_ns`` holds its length once it has closed."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0_ns", "_parent")
+    __slots__ = ("_tracer", "name", "args", "_t0_ns", "_parent", "_note",
+                 "dur_ns")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Dict):
         self._tracer = tracer
@@ -60,6 +71,8 @@ class _Span:
         self.args = args
         self._t0_ns = 0
         self._parent: Optional[str] = None
+        self._note = None
+        self.dur_ns = 0
 
     def set(self, **attrs) -> "_Span":
         self.args.update(attrs)
@@ -69,11 +82,15 @@ class _Span:
         stack = self._tracer._stack()
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
+        # the profiler's event starts where the annotation is built
+        self._note = self._tracer._annotation(self.name)
+        self._note.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur_ns = time.perf_counter_ns() - self._t0_ns
+        dur_ns = self.dur_ns = time.perf_counter_ns() - self._t0_ns
+        self._note.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -95,6 +112,7 @@ class SpanTracer:
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.enabled = False
+        self.ops = False                # per-op lifecycle instants too
         self.capacity = capacity
         self.dropped = 0
         self._events: deque = deque(maxlen=capacity)
@@ -139,7 +157,11 @@ class SpanTracer:
         get_registry().counter("spans_dropped", component="obs").inc(n)
 
     # -- lifecycle -------------------------------------------------------------
-    def enable(self, capacity: Optional[int] = None) -> "SpanTracer":
+    def enable(self, capacity: Optional[int] = None,
+               ops: bool = False) -> "SpanTracer":
+        # the profiler mirror is only ever needed once tracing is on
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         if capacity is not None and capacity != self.capacity:
             with self._lock:
                 # shrinking below the buffered count discards the oldest
@@ -149,6 +171,7 @@ class SpanTracer:
                     self._count_dropped(lost)
                 self.capacity = capacity
                 self._events = deque(self._events, maxlen=capacity)
+        self.ops = ops
         self.enabled = True
         return self
 
@@ -188,8 +211,10 @@ def instant(name: str, **attrs) -> None:
     _TRACER.instant(name, **attrs)
 
 
-def enable_tracing(capacity: Optional[int] = None) -> SpanTracer:
-    return _TRACER.enable(capacity)
+def enable_tracing(capacity: Optional[int] = None,
+                   ops: bool = False) -> SpanTracer:
+    """Record spans (and, with ``ops``, the per-op lifecycle instants)."""
+    return _TRACER.enable(capacity, ops)
 
 
 def disable_tracing() -> SpanTracer:
@@ -198,3 +223,8 @@ def disable_tracing() -> SpanTracer:
 
 def tracing_enabled() -> bool:
     return _TRACER.enabled
+
+
+def op_tracing() -> bool:
+    """Whether the per-op lifecycle instants are recorded."""
+    return _TRACER.enabled and _TRACER.ops

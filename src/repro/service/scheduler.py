@@ -37,7 +37,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence
 
-from repro.obs import instant, span, tracing_enabled
+from repro.obs import instant, op_tracing, span
 from repro.pmwcas import Backend, MwCASOp, OpResult, Target
 
 from .executor import execute_wave, schedule_wave, select_executor
@@ -136,7 +136,7 @@ class BatchScheduler:
         fut = OpFuture(op, client, routed.shard, self._seq, self.stats.steps)
         self._seq += 1
         self.stats.submitted += 1
-        if tracing_enabled():
+        if op_tracing():
             instant("op.submit", op_id=fut.op_id, client=client,
                     shard=routed.shard, cross=routed.is_cross,
                     step=self.stats.steps)
@@ -383,7 +383,7 @@ class BatchScheduler:
             fut.latency_rounds, status, latency_us=latency_us,
             queue_us=queue_us, dispatch_us=dispatch_us,
             persist_us=persist_us, retry_waves=0)
-        if tracing_enabled():
+        if op_tracing():
             instant("op.complete", op_id=fut.op_id, status=status,
                     queue_us=round(queue_us, 1),
                     dispatch_us=round(dispatch_us, 1),

@@ -25,8 +25,8 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 from repro import PMemPool
-from repro.obs import (flush_reason, instant, reset_metrics, span,
-                       tracing_enabled)
+from repro.obs import (flush_reason, instant, op_tracing, reset_metrics,
+                       span, tracing_enabled)
 from repro.pmwcas import Backend, MwCASOp, make_backend
 from repro.structures import (BzTreeIndex, DELETE, EXHAUSTED, FULL, HashMap,
                               INSERT, KVOp, NeedsResize, NeedsSplit, OK,
@@ -225,7 +225,7 @@ class KVService:
         fut = KVFuture(op, client, shard, self._seq, self.stats.steps)
         self._seq += 1
         self.stats.submitted += 1
-        if tracing_enabled():
+        if op_tracing():
             instant("op.submit", op_id=fut.op_id, client=client,
                     shard=shard, kind=op.kind, step=self.stats.steps)
         mig = self._covering_migration(op)
@@ -311,20 +311,30 @@ class KVService:
                           for _p, ok in pairs if ok)
             persist_share_us = (persist_wave_ns / 1e3 / winners
                                 if winners else 0.0)
+            timed = tracing_enabled()
             for s, pairs in wave.items():
-                losers = []
-                for pending, ok in pairs:
-                    if ok:
-                        self._finish(pending.future, OK,
-                                     dispatch_start_ns=dispatch_start_ns,
-                                     persist_share_us=persist_share_us,
-                                     retry_waves=pending.attempts)
-                        completed += 1
-                    else:
-                        pending.attempts += 1
-                        losers.append(pending)   # recompile next wave
+                completed += self._finish_all(
+                    [(p, OK, None) for p, ok in pairs if ok], timed,
+                    dispatch_start_ns=dispatch_start_ns,
+                    persist_share_us=persist_share_us)
+                losers = [p for p, ok in pairs if not ok]
+                for pending in losers:
+                    pending.attempts += 1        # recompile next wave
                 self._requeue(s, losers)
         return completed
+
+    def _finish_all(self, answered: List[tuple], timed: bool,
+                    **kw) -> int:
+        """:meth:`_finish` each ``(pending, status, value)``; with
+        ``timed`` (tracing enabled) their time adds to
+        ``stats.complete_ns``.  Returns how many."""
+        t0 = time.perf_counter_ns() if timed else 0
+        for pending, status, value in answered:
+            self._finish(pending.future, status, value,
+                         retry_waves=pending.attempts, **kw)
+        if timed:
+            self.stats.complete_ns += time.perf_counter_ns() - t0
+        return len(answered)
 
     def _persist_ns_total(self) -> int:
         """Wall-clock the durable shards have spent inside persist
@@ -381,20 +391,31 @@ class KVService:
             # an in-flight directory doubling pumps a chunk per wave
             with flush_reason("structures", "doubling_pump"):
                 struct.resize_step(max_moves=max(len(self._queues[s]), 2))
-        snap = struct.snapshot()
+        with span("wave.snapshot", shard=s) as sp:
+            snap = struct.snapshot()
+        self.stats.snapshot_ns += sp.dur_ns
+        timed, clock = tracing_enabled(), time.perf_counter_ns
+        compile_ns = exhausted = 0
         ready: List[_PendingKV] = []
         later: List[_PendingKV] = []
-        done = 0
+        # (pending, status, value): answered here, completed once the
+        # whole queue is compiled
+        answered: List[tuple] = []
         splits: Dict[int, List[_PendingKV]] = {}
         resizes: List[_PendingKV] = []
-        for pending in self._queues[s]:
+        queue = self._queues[s]
+        for pending in queue:
             fut = pending.future
             if pending.attempts > self.max_op_rounds:
-                self._finish(fut, EXHAUSTED,
-                             retry_waves=pending.attempts)
-                done += 1
+                answered.append((pending, EXHAUSTED, None))
+                exhausted += 1
                 continue
-            compiled = struct.compile_op(fut.op, snap)
+            if timed:
+                t0 = clock()
+                compiled = struct.compile_op(fut.op, snap)
+                compile_ns += clock() - t0
+            else:
+                compiled = struct.compile_op(fut.op, snap)
             if isinstance(compiled, NeedsResize):
                 resizes.append(pending)
             elif isinstance(compiled, StructResult):
@@ -407,17 +428,18 @@ class KVService:
                          or 0)
                         for s2, other in enumerate(self.structs)
                         if s2 != s)
-                    self._finish(fut, OK, value,
-                                 retry_waves=pending.attempts)
+                    answered.append((pending, OK, value))
                 else:
-                    self._finish(fut, compiled.status, compiled.value,
-                                 retry_waves=pending.attempts)
-                done += 1
+                    answered.append((pending, compiled.status,
+                                     compiled.value))
             elif isinstance(compiled, NeedsSplit):
                 splits.setdefault(compiled.leaf_base, []).append(pending)
             else:
                 pending.local = compiled
                 ready.append(pending)
+        self.stats.ops_compiled += len(queue) - exhausted
+        self.stats.compile_ns += compile_ns
+        done = self._finish_all(answered, timed)
         self._queues[s] = []
         if resizes:
             # publish the doubling decision; the waiters recompile next
@@ -430,10 +452,8 @@ class KVService:
                     pending.attempts += 1
                 later.extend(resizes)
             else:
-                for pending in resizes:
-                    self._finish(pending.future, FULL,
-                                 retry_waves=pending.attempts)
-                    done += 1
+                done += self._finish_all(
+                    [(p, FULL, None) for p in resizes], timed)
         if splits:
             # grow first; this wave's compiled ops would mostly lose
             # (the split freezes their leaf's meta), so everything on
@@ -449,10 +469,8 @@ class KVService:
                         pending.attempts += 1
                     later.extend(waiters)
                 else:
-                    for pending in waiters:
-                        self._finish(pending.future, FULL,
-                                     retry_waves=pending.attempts)
-                        done += 1
+                    done += self._finish_all(
+                        [(p, FULL, None) for p in waiters], timed)
             self._requeue(s, ready + later)
             return [], done
         self._requeue(s, later)
@@ -462,7 +480,7 @@ class KVService:
         """Merge entries back into the shard queue in submission order
         (FIFO fairness across defers, losses and recompiles)."""
         if entries:
-            if tracing_enabled():
+            if op_tracing():
                 for pending in entries:
                     instant("op.requeue", op_id=pending.future.op_id,
                             shard=s, attempts=pending.attempts,
@@ -490,7 +508,7 @@ class KVService:
                 persist_share_us=persist_share_us,
                 retry_waves=retry_waves)))
             self.stats.acks_held += 1
-            if tracing_enabled():
+            if op_tracing():
                 instant("op.ack_held", op_id=fut.op_id, status=status,
                         step=self.stats.steps)
         else:
@@ -529,6 +547,8 @@ class KVService:
         passed (``None`` = everything), in decide order."""
         if not self._held:
             return
+        timed = tracing_enabled()
+        t0 = time.perf_counter_ns() if timed else 0
         keep: List[tuple] = []
         for item in self._held:
             step, fut, status, value, kw = item
@@ -537,6 +557,8 @@ class KVService:
             else:
                 keep.append(item)
         self._held = keep
+        if timed:
+            self.stats.complete_ns += time.perf_counter_ns() - t0
 
     def sync_epochs(self) -> int:
         """Explicit durability barrier: close every shard's open epoch
@@ -583,7 +605,7 @@ class KVService:
             latency, status, latency_us=latency_us, queue_us=queue_us,
             dispatch_us=dispatch_us, persist_us=persist_us,
             retry_waves=retry_waves)
-        if tracing_enabled():
+        if op_tracing():
             instant("op.complete", op_id=fut.op_id, status=status,
                     latency_us=round(latency_us, 1),
                     queue_us=round(queue_us, 1),

@@ -20,7 +20,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.obs import Histogram, get_registry
+from repro.obs import Histogram
 from repro.pmwcas import DurabilityStats
 
 
@@ -99,6 +99,15 @@ class ServiceStats:
     retry_waves: Histogram = dataclasses.field(
         default_factory=lambda: Histogram("service.retry_waves"))
     by_status: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # where a wave's host time goes (KVService): ops handed to
+    # compile_op, always counted; and, while tracing is enabled only,
+    # the nanoseconds of the whole-table snapshots (the ``wave.snapshot``
+    # spans), of compile_op, and of completing ops (``_finish``, and
+    # ``_complete`` for acks released later)
+    ops_compiled: int = 0
+    snapshot_ns: int = 0
+    compile_ns: int = 0
+    complete_ns: int = 0
 
     # percentile window: a long-running service would otherwise grow the
     # sample list without bound; the percentiles describe recent traffic
@@ -118,24 +127,14 @@ class ServiceStats:
                                - self.MAX_LATENCY_SAMPLES]
         if latency_us is not None:
             self.latency_us.record(latency_us)
-        # mirror the breakdown into the global registry (same series the
-        # benchmark windows and obs_report read) alongside the dataclass
-        reg = get_registry()
         if queue_us is not None:
             self.queue_us.record(queue_us)
-            reg.histogram("queue_us", component="service").record(queue_us)
         if dispatch_us is not None:
             self.dispatch_us.record(dispatch_us)
-            reg.histogram("dispatch_us",
-                          component="service").record(dispatch_us)
         if persist_us is not None:
             self.persist_us.record(persist_us)
-            reg.histogram("persist_us",
-                          component="service").record(persist_us)
         if retry_waves is not None:
             self.retry_waves.record(retry_waves)
-            reg.histogram("retry_waves",
-                          component="service").record(retry_waves)
         self.by_status[status] = self.by_status.get(status, 0) + 1
 
     # -- aggregates ------------------------------------------------------------
