@@ -68,7 +68,7 @@ def _emit_kv(name: str, row: dict):
                   f"persist_us_p99={row['persist_us_p99']:.1f};"
                   f"retry_waves_max={row['retry_waves_max']}")
         # the three components partition each op's latency BY
-        # CONSTRUCTION (service._complete), so their means must
+        # CONSTRUCTION (KVService._answer), so their means must
         # reconcile with the latency mean to rounding noise
         parts = (row["queue_us_mean"] + row["dispatch_us_mean"]
                  + row["persist_us_mean"])

@@ -31,8 +31,10 @@ writers are the service wave loop and its helpers).
 """
 from __future__ import annotations
 
+import operator
 import threading
-from typing import Dict, Hashable, List, Optional, Tuple
+from functools import reduce
+from typing import Dict, Hashable, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -120,6 +122,24 @@ class Histogram:
         self.total_us += us
         if us > self.max_us:
             self.max_us = us
+        return self
+
+    def record_many(self, values: Iterable[float]) -> "Histogram":
+        """``record`` each value in order, trimming the window once: the
+        same samples, ``count``, ``total_us`` (summed left to right) and
+        ``max_us`` as one ``record`` call per value."""
+        values = list(map(float, values))
+        if not values:
+            return self
+        samples = self.samples
+        samples.extend(values)
+        if len(samples) > self.window:
+            del samples[:len(samples) - self.window]
+        self.count += len(values)
+        self.total_us = reduce(operator.add, values, self.total_us)
+        top = max(values)
+        if top > self.max_us:
+            self.max_us = top
         return self
 
     def percentile(self, q: float) -> float:

@@ -369,7 +369,7 @@ class BatchScheduler:
         status = "ok" if success else "conflict"
         latency_us = (time.perf_counter_ns() - fut.submit_ns) / 1e3
         # queue + dispatch + persist partition latency_us exactly (the
-        # same decomposition as KVService._complete; the scheduler
+        # same decomposition as KVService._answer; the scheduler
         # executes each submission once, so retry_waves is always 0)
         if dispatch_start_ns is None:
             queue_us, dispatch_us, persist_us = latency_us, 0.0, 0.0

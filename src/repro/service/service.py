@@ -154,8 +154,9 @@ class KVService:
             backend, n_shards, words, durable_root, group_commit,
             self.epoch_rounds, self.checkpoint_every, use_kernel)
         self.structs = [self._attach(b) for b in self.backends]
-        # epoch ack gate (DESIGN.md Sec. 14): decisions made while ANY
-        # durable shard has an open epoch are withheld here, in decide
+        # epoch ack gate (DESIGN.md Sec. 14): batches of decisions made
+        # while ANY durable shard has an open epoch are withheld here,
+        # (step, dispatch_start_ns, persist_share_us, answered) in decide
         # order, until the global durability frontier passes them
         self._held: List[tuple] = []
         self._epoch_open_since: Dict[int, int] = {}
@@ -245,7 +246,7 @@ class KVService:
         # drain() must not return while an epoch still owes them a fence
         return sum(len(q) for q in self._queues) \
             + sum(len(m.held) for m in self._migrations) \
-            + len(self._held)
+            + sum(len(held[3]) for held in self._held)
 
     # -- execution -------------------------------------------------------------
     def step(self) -> int:
@@ -324,17 +325,102 @@ class KVService:
         return completed
 
     def _finish_all(self, answered: List[tuple], timed: bool,
-                    **kw) -> int:
-        """:meth:`_finish` each ``(pending, status, value)``; with
-        ``timed`` (tracing enabled) their time adds to
+                    dispatch_start_ns: Optional[int] = None,
+                    persist_share_us: float = 0.0) -> int:
+        """Answer one batch of ``(pending, status, value)`` decided this
+        wave: one shard's compile-time answers, its FULL verdicts, or
+        its round winners (with the wave's ``dispatch_start_ns`` and
+        per-winner ``persist_share_us``).  The epoch ack gate is checked
+        once: answering changes no backend state, so every op of the
+        batch meets the same gate.  While ANY durable shard has an open
+        epoch the whole batch is withheld, and released in decide order
+        once every shard has durably passed the deciding step.  A global
+        gate (not per-shard) because cross-shard reads (scans) observe
+        every shard's visible state: acking a scan before a slower
+        shard's epoch closes could expose a round a crash then revokes.
+        With ``timed`` (tracing enabled) the time adds to
         ``stats.complete_ns``.  Returns how many."""
+        if not answered:
+            return 0
         t0 = time.perf_counter_ns() if timed else 0
-        for pending, status, value in answered:
-            self._finish(pending.future, status, value,
-                         retry_waves=pending.attempts, **kw)
+        step = self.stats.steps
+        if any(getattr(b, "epoch_pending", 0) for b in self.backends):
+            self._held.append((step, dispatch_start_ns, persist_share_us,
+                               answered))
+            self.stats.acks_held += len(answered)
+            if op_tracing():
+                for pending, status, _value in answered:
+                    instant("op.ack_held", op_id=pending.future.op_id,
+                            status=status, step=step)
+        else:
+            self._answer(answered, step, dispatch_start_ns,
+                         persist_share_us)
         if timed:
             self.stats.complete_ns += time.perf_counter_ns() - t0
         return len(answered)
+
+    def _answer(self, answered: List[tuple], decided_step: int,
+                dispatch_start_ns: Optional[int],
+                persist_share_us: float) -> None:
+        """Complete a batch of ``(pending, status, value)`` decided in
+        ``decided_step``: the one completion path, for answers the gate
+        lets through and for held acks released later.  One clock read
+        stamps the whole batch (no client sees an answer before
+        ``step()`` returns), and the statistics are recorded in bulk.
+
+        Each op's latency decomposes into queue (submit -> the wave's
+        dispatch start), persist (the op's share of the wave's fence
+        wall-clock) and dispatch (the rest); the three sum to latency_us
+        exactly.  Compile-time answers (reads, EXHAUSTED, FULL) never
+        reach a dispatch, so their whole latency is queueing."""
+        steps = self.stats.steps
+        now_ns = time.perf_counter_ns()
+        complete = self._complete
+        share = max(persist_share_us, 0.0)
+        rows = []
+        row = rows.append
+        for pending, status, value in answered:
+            fut = pending.future
+            rounds = steps - fut.submit_step
+            if rounds < 1:
+                rounds = 1
+            complete(fut, status, value, decided_step, rounds)
+            latency_us = (now_ns - fut.submit_ns) / 1e3
+            if dispatch_start_ns is None:
+                row((rounds, status, latency_us, latency_us, 0.0, 0.0,
+                     pending.attempts))
+                continue
+            # min(max(.., 0.0), ..) written out, with their tie rules
+            queue_us = (dispatch_start_ns - fut.submit_ns) / 1e3
+            if 0.0 > queue_us:
+                queue_us = 0.0
+            if latency_us < queue_us:
+                queue_us = latency_us
+            rest_us = latency_us - queue_us
+            persist_us = rest_us if rest_us < share else share
+            row((rounds, status, latency_us, queue_us, rest_us - persist_us,
+                 persist_us, pending.attempts))
+        stats = self.stats
+        stats.record_completions(*zip(*rows))
+        stats.complete_batches += 1
+        if op_tracing():
+            for (pending, _s, _v), (_r, status, latency_us, queue_us,
+                                    dispatch_us, persist_us, retry_waves) \
+                    in zip(answered, rows):
+                instant("op.complete", op_id=pending.future.op_id,
+                        status=status, latency_us=round(latency_us, 1),
+                        queue_us=round(queue_us, 1),
+                        dispatch_us=round(dispatch_us, 1),
+                        persist_us=round(persist_us, 1),
+                        retry_waves=retry_waves, step=steps)
+
+    def _complete(self, fut: KVFuture, status: str, value,
+                  done_step: int, rounds: int) -> None:
+        """Hand one future its answer: the only per-op call of
+        :meth:`_answer`, a method so that a test can substitute it."""
+        fut.done = True
+        fut.done_step = done_step
+        fut.result = StructResult(fut.op, status, value, rounds)
 
     def _persist_ns_total(self) -> int:
         """Wall-clock the durable shards have spent inside persist
@@ -489,34 +575,6 @@ class KVService:
             self._queues[s].sort(key=lambda p: p.future.seq)
 
     # -- epoch ack gate (DESIGN.md Sec. 14) ------------------------------------
-    def _finish(self, fut: KVFuture, status: str, value=None, *,
-                dispatch_start_ns: Optional[int] = None,
-                persist_share_us: float = 0.0,
-                retry_waves: int = 0) -> None:
-        """Completion gate for the epoch window.  The decision (status/
-        value) is final here, but while ANY durable shard has an open
-        epoch the ack is withheld GLOBALLY — released in decide order
-        once every shard has durably passed the deciding step.  A
-        global gate (not per-shard) because cross-shard reads (scans)
-        observe every shard's visible state: acking a scan before a
-        slower shard's epoch closes could expose a round a crash then
-        revokes.  Outside epoch mode the gate is always open and this
-        is exactly :meth:`_complete`."""
-        if any(getattr(b, "epoch_pending", 0) for b in self.backends):
-            self._held.append((self.stats.steps, fut, status, value, dict(
-                dispatch_start_ns=dispatch_start_ns,
-                persist_share_us=persist_share_us,
-                retry_waves=retry_waves)))
-            self.stats.acks_held += 1
-            if op_tracing():
-                instant("op.ack_held", op_id=fut.op_id, status=status,
-                        step=self.stats.steps)
-        else:
-            self._complete(fut, status, value,
-                           dispatch_start_ns=dispatch_start_ns,
-                           persist_share_us=persist_share_us,
-                           retry_waves=retry_waves)
-
     def _settle_epochs(self) -> None:
         """End-of-wave epoch bookkeeping: note which shards hold an open
         epoch (and since when), then release held acks up to the global
@@ -543,19 +601,21 @@ class KVService:
             self._release_held(frontier)
 
     def _release_held(self, frontier: Optional[int]) -> None:
-        """Ack held completions whose deciding step the frontier has
-        passed (``None`` = everything), in decide order."""
+        """Ack held batches whose deciding step the frontier has passed
+        (``None`` = everything), in decide order, each with its own
+        step, dispatch start and persist share."""
         if not self._held:
             return
         timed = tracing_enabled()
         t0 = time.perf_counter_ns() if timed else 0
         keep: List[tuple] = []
-        for item in self._held:
-            step, fut, status, value, kw = item
-            if frontier is None or step <= frontier:
-                self._complete(fut, status, value, decided_step=step, **kw)
+        for held in self._held:
+            if frontier is None or held[0] <= frontier:
+                step, dispatch_start_ns, persist_share_us, answered = held
+                self._answer(answered, step, dispatch_start_ns,
+                             persist_share_us)
             else:
-                keep.append(item)
+                keep.append(held)
         self._held = keep
         if timed:
             self.stats.complete_ns += time.perf_counter_ns() - t0
@@ -574,44 +634,6 @@ class KVService:
         self._epoch_open_since.clear()
         self._release_held(None)
         return synced
-
-    def _complete(self, fut: KVFuture, status: str, value=None, *,
-                  dispatch_start_ns: Optional[int] = None,
-                  persist_share_us: float = 0.0,
-                  retry_waves: int = 0,
-                  decided_step: Optional[int] = None) -> None:
-        fut.done = True
-        fut.done_step = (self.stats.steps if decided_step is None
-                         else decided_step)
-        latency = max(1, self.stats.steps - fut.submit_step)
-        fut.result = StructResult(fut.op, status, value=value,
-                                  rounds=latency)
-        now_ns = time.perf_counter_ns()
-        latency_us = (now_ns - fut.submit_ns) / 1e3
-        # decompose: queue (submit -> this wave's dispatch start),
-        # persist (the op's share of the wave's fence wall-clock),
-        # dispatch (the rest).  The three sum to latency_us exactly —
-        # compile-time completions (reads, EXHAUSTED, FULL) never reach
-        # a dispatch, so their whole latency is queueing.
-        if dispatch_start_ns is None:
-            queue_us, dispatch_us, persist_us = latency_us, 0.0, 0.0
-        else:
-            queue_us = min(max(
-                (dispatch_start_ns - fut.submit_ns) / 1e3, 0.0), latency_us)
-            persist_us = min(max(persist_share_us, 0.0),
-                             latency_us - queue_us)
-            dispatch_us = latency_us - queue_us - persist_us
-        self.stats.record_completion(
-            latency, status, latency_us=latency_us, queue_us=queue_us,
-            dispatch_us=dispatch_us, persist_us=persist_us,
-            retry_waves=retry_waves)
-        if op_tracing():
-            instant("op.complete", op_id=fut.op_id, status=status,
-                    latency_us=round(latency_us, 1),
-                    queue_us=round(queue_us, 1),
-                    dispatch_us=round(dispatch_us, 1),
-                    persist_us=round(persist_us, 1),
-                    retry_waves=retry_waves, step=self.stats.steps)
 
     # -- online key-range migration --------------------------------------------
     def _covering_migration(self, op: KVOp) -> Optional[_Migration]:

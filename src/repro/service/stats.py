@@ -15,6 +15,7 @@ hits of the stacked dispatch) attaches after every wave, and
 commit fences) for durable deployments."""
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
@@ -100,11 +101,14 @@ class ServiceStats:
         default_factory=lambda: Histogram("service.retry_waves"))
     by_status: Dict[str, int] = dataclasses.field(default_factory=dict)
     # where a wave's host time goes (KVService): ops handed to
-    # compile_op, always counted; and, while tracing is enabled only,
-    # the nanoseconds of the whole-table snapshots (the ``wave.snapshot``
-    # spans), of compile_op, and of completing ops (``_finish``, and
-    # ``_complete`` for acks released later)
+    # compile_op and batches of answers completed (completed ÷
+    # complete_batches is the ops per batch), always counted; and, while
+    # tracing is enabled only, the nanoseconds of the whole-table
+    # snapshots (the ``wave.snapshot`` spans), of compile_op, and of
+    # completing ops (``_finish_all``, and ``_release_held`` for acks
+    # released later)
     ops_compiled: int = 0
+    complete_batches: int = 0
     snapshot_ns: int = 0
     compile_ns: int = 0
     complete_ns: int = 0
@@ -136,6 +140,31 @@ class ServiceStats:
         if retry_waves is not None:
             self.retry_waves.record(retry_waves)
         self.by_status[status] = self.by_status.get(status, 0) + 1
+
+    def record_completions(self, latency_rounds: Sequence[int],
+                           statuses: Sequence[str],
+                           latency_us: Sequence[float],
+                           queue_us: Sequence[float],
+                           dispatch_us: Sequence[float],
+                           persist_us: Sequence[float],
+                           retry_waves: Sequence[int]) -> None:
+        """A batch of completions, one entry per op in every list: the
+        same windows, counts and ``by_status`` as one
+        :meth:`record_completion` per op in order, with each window
+        trimmed once per batch."""
+        self.completed += len(statuses)
+        latencies = self.latencies
+        latencies.extend(map(int, latency_rounds))
+        if len(latencies) > self.MAX_LATENCY_SAMPLES:
+            del latencies[:len(latencies) - self.MAX_LATENCY_SAMPLES]
+        self.latency_us.record_many(latency_us)
+        self.queue_us.record_many(queue_us)
+        self.dispatch_us.record_many(dispatch_us)
+        self.persist_us.record_many(persist_us)
+        self.retry_waves.record_many(retry_waves)
+        by_status = self.by_status
+        for status, n in collections.Counter(statuses).items():
+            by_status[status] = by_status.get(status, 0) + n
 
     # -- aggregates ------------------------------------------------------------
     @property

@@ -12,6 +12,7 @@ the accounting contracts the rest of the stack now relies on:
 """
 import json
 
+import numpy as np
 import pytest
 
 from repro.obs import (NULL_SPAN, Counter, Histogram, MetricsRegistry,
@@ -21,7 +22,7 @@ from repro.obs import (NULL_SPAN, Counter, Histogram, MetricsRegistry,
                        reset_metrics, span, span_tree,
                        validate_chrome_trace)
 from repro.pmwcas import DurabilityStats, DurableBackend, MwCASOp
-from repro.service import KVService
+from repro.service import KVService, fresh_stats
 from repro.structures import KVOp
 
 
@@ -74,6 +75,60 @@ def test_histogram_percentiles_and_bounded_window():
     assert 60.0 <= h.p50_us <= 75.0
     assert h.p99_us >= 99.0
     assert h.summary()["count"] == 100
+
+
+WINDOW = Histogram.DEFAULT_WINDOW
+BATCH_SIZES = [0, 1, WINDOW - 1, 3 * WINDOW]
+
+
+def _floats(rng, n):
+    # uneven magnitudes, so a sum in another order would differ
+    return list(rng.lognormal(3.0, 2.0, size=n))
+
+
+def _same_histogram(a: Histogram, b: Histogram):
+    assert a.samples == b.samples
+    assert (a.count, a.total_us, a.max_us) == (b.count, b.total_us,
+                                               b.max_us)
+    assert (a.p50_us, a.p99_us) == (b.p50_us, b.p99_us)
+
+
+@pytest.mark.parametrize("n", BATCH_SIZES)
+def test_histogram_record_many_equals_record_in_order(n):
+    rng = np.random.default_rng(n)
+    prior, batch = _floats(rng, WINDOW - 2), _floats(rng, n)
+    one, many = Histogram("one"), Histogram("many")
+    for us in prior:
+        one.record(us)
+        many.record(us)
+    for us in batch:
+        one.record(us)
+    assert many.record_many(batch) is many
+    _same_histogram(one, many)
+
+
+@pytest.mark.parametrize("n", BATCH_SIZES)
+def test_record_completions_equals_record_completion_in_order(n):
+    rng = np.random.default_rng(n + 1)
+    rows = [(int(rng.integers(1, 9)),
+             ("ok", "not_found", "exists", "full")[int(rng.integers(4))],
+             *_floats(rng, 4), int(rng.integers(0, 3)))
+            for _ in range(WINDOW - 2 + n)]
+    one, many = fresh_stats(2, round_cap=4), fresh_stats(2, round_cap=4)
+    names = ("latency_us", "queue_us", "dispatch_us", "persist_us",
+             "retry_waves")
+    for i, (rounds, status, *values) in enumerate(rows):
+        one.record_completion(rounds, status, **dict(zip(names, values)))
+        if i < WINDOW - 2:
+            many.record_completion(rounds, status,
+                                   **dict(zip(names, values)))
+    if n:
+        many.record_completions(*zip(*rows[WINDOW - 2:]))
+    assert many.completed == one.completed == len(rows)
+    assert many.latencies == one.latencies
+    assert list(many.by_status.items()) == list(one.by_status.items())
+    for name in names:
+        _same_histogram(getattr(one, name), getattr(many, name))
 
 
 def test_counter_allows_corrective_negative_deltas():
