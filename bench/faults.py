@@ -16,12 +16,22 @@ plant nothing; ``bench.control`` and the tests in ``tests/bench`` do.
 - ``half_batch``: half of each shard's round left out of the apply and
   answered as committed.
 - ``altered_answer``: a read answer altered where it is produced.
+- ``skipped_persist``, for a durable deployment: every durable shard's
+  ``persist`` does nothing, so its writes stay unpersisted and the crash
+  after the window drops them, though every answer and the served table
+  are right.
+- ``unsynced_persist``, for a durable deployment: ``persist`` keeps its
+  bookkeeping (the file is counted as persisted, and the program's own
+  crash model would keep it) but skips its ``fsync``: the later or rarer
+  flush that only the harness's own model of the medium
+  (``bench.durable``) catches.
 
 No cell runs on more than one chip, so no fault drops an exchange
 between chips.
 """
 from __future__ import annotations
 
+import sys
 from typing import Callable, Dict
 
 import numpy as np
@@ -95,10 +105,57 @@ def altered_answer(svc) -> Callable[[], None]:
     return undo
 
 
+def skipped_persist(svc) -> Callable[[], None]:
+    pools = [b.pool for b in svc.backends if hasattr(b, "pool")]
+    if not pools:
+        raise TypeError("skipped_persist needs durable shards; this "
+                        "service has none")
+    for pool in pools:
+        pool.persist = lambda rel: None
+
+    def undo():
+        for pool in pools:
+            del pool.persist
+    return undo
+
+
+class _NoSync:
+    """An ``os`` module whose ``fsync`` and ``fdatasync`` do nothing."""
+
+    def __init__(self, real):
+        self.real = real
+
+    def __getattr__(self, name):
+        return getattr(self.real, name)
+
+    @staticmethod
+    def fsync(fd):
+        pass
+
+    fdatasync = fsync
+
+
+def unsynced_persist(svc) -> Callable[[], None]:
+    modules = {sys.modules[type(b.pool).__module__]
+               for b in svc.backends if hasattr(b, "pool")}
+    if not modules:
+        raise TypeError("unsynced_persist needs durable shards; this "
+                        "service has none")
+    for module in modules:
+        module.os = _NoSync(module.os)
+
+    def undo():
+        for module in modules:
+            module.os = module.os.real
+    return undo
+
+
 CONTROL = "stale_snapshot"
 PLANTS: Dict[str, Callable] = {
     "stale_snapshot": stale_snapshot,
     "frozen_state": frozen_state,
     "half_batch": half_batch,
     "altered_answer": altered_answer,
+    "skipped_persist": skipped_persist,
+    "unsynced_persist": unsynced_persist,
 }

@@ -16,13 +16,26 @@ From the root of a checkout.  In order:
    program's spans and a ``jax.profiler`` trace);
 6. answer every op still in flight, then replay every answered op on the
    plain reference (``bench.reference``) and compare the final table;
-7. print the checks, each beside its limit, as the last lines on
+7. for a durable deployment (shards that recover from a medium), crash
+   the service with no barrier before it: put its root back to the bytes
+   that the process's syncs made durable (``bench.durable``, the
+   harness's own model, not the program's), restart the service on that
+   root, recover every shard, and compare the recovered table with the
+   reference's: every acknowledged write has to survive;
+8. print the checks, each beside its limit, as the last lines on
    standard error, and the result as the last line of standard output.
 
 Set-up (``setup_s``) runs from the start of the process to the end of
 the warm-up, and so holds every compilation; any compilation inside the
-window fails the run.  The reference runs after the window and after the
-device's peak memory is read, and is not counted in either.
+window fails the run.  The reference, and the crash and recovery after
+it, run after the window and after the device's peak memory is read,
+and are counted in neither.
+
+Whether a deployment is durable is read from its shards: where they
+can recover from a medium, the service is built on a fresh directory of
+the checkout, ``.bench/durable/<cell>-<pid>/``, removed after the run;
+the run prints that directory's filesystem type and fails where it is
+one that an ``fsync`` cannot make durable.
 """
 from __future__ import annotations
 
@@ -44,7 +57,7 @@ from typing import Callable, Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
-from bench import devtrace, reference, spec, ycsb  # noqa: E402
+from bench import devtrace, durable, reference, spec, ycsb  # noqa: E402
 from bench.traced import TracedRun  # noqa: E402
 
 sys.path.insert(0, str(spec.ROOT / "src"))
@@ -56,6 +69,8 @@ PROGRAM_SPANS = ("service.wave", "wave.compile", "wave.schedule",
 APPLY_PROGRAM = "jit_pmwcas_apply_stacked"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# a durable deployment's medium: a directory per run under DURABLE_DIR
+DURABLE_DIR = spec.ROOT / ".bench" / "durable"
 
 
 def say(msg: str) -> None:
@@ -282,6 +297,34 @@ def load(svc, keys: np.ndarray, values: np.ndarray, queued: int):
     return waves, errors
 
 
+def crash_and_recover(medium: durable.Medium, restart: Callable,
+                      state: Dict[int, int]):
+    """Crash the service whose root ``medium`` watched: put the root
+    back to what was synced, ``restart()`` a service on it and recover
+    every shard; compare the recovered table with ``state``, every
+    acknowledged write.  Returns the checks and the seconds the restart
+    and recovery took."""
+    reverted, dropped = medium.crash()
+    say(f"crash: {reverted} files put back to their synced bytes, "
+        f"{dropped} never synced dropped")
+    t0 = time.perf_counter()
+    svc = restart()
+    for b in svc.backends:
+        b.recover()
+    recover_s = time.perf_counter() - t0
+    items = svc.items()
+    try:
+        integrity_errors = int(svc.check_integrity() != items)
+    except (AssertionError, RuntimeError) as e:
+        say(f"recovered integrity check failed: {e}")
+        integrity_errors = 1
+    return {
+        "recovered_mismatched_keys": (reference.differing_keys(items,
+                                                               state), 0),
+        "recovered_integrity_errors": (integrity_errors, 0),
+    }, recover_s
+
+
 def _percentile_ms(lat: array.array, q: float) -> Optional[float]:
     if not len(lat):
         return None
@@ -329,14 +372,30 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     from repro.service import KVService
 
     cfg = cell.config
+    service_args = dict(structure=cfg["structure"], backend=cfg["backend"],
+                        n_buckets=cfg["buckets_per_shard"],
+                        round_cap=cfg["round_cap"])
+    root = medium = None
     compiles = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     jax.monitoring.register_event_listener(compiles.event)
     try:
-        svc = KVService(cfg["shards"], structure=cfg["structure"],
-                        backend=cfg["backend"],
-                        n_buckets=cfg["buckets_per_shard"],
-                        round_cap=cfg["round_cap"])
+        svc = KVService(cfg["shards"], **service_args)
+        if any(callable(getattr(b, "recover", None)) for b in svc.backends):
+            # shards that recover from a medium: built again on a root in
+            # the checkout, whose syncs the harness keeps, and crashed
+            # after the window
+            root = DURABLE_DIR / f"{cell.name}-{os.getpid()}"
+            shutil.rmtree(root, ignore_errors=True)
+            root.mkdir(parents=True)
+            medium = durable.Medium(root).start()
+            fs = durable.filesystem_type(root)
+            say(f"durable medium: {root} on {fs}")
+
+            def restart():
+                return KVService(cfg["shards"], durable_root=str(root),
+                                 **service_args)
+            svc = restart()
         keys, values = ycsb.load_records(cfg["recordcount"], seed)
         t0 = time.perf_counter()
         load_waves, load_errors = load(svc, keys, values,
@@ -417,9 +476,22 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             say(f"mismatch: {ex}")
         say(f"reference: {rep.checked} answers replayed, "
             f"{time.perf_counter() - t_ref:.3f} s")
+
+        if medium is not None:
+            # no barrier: a deployment's clients call none, so every
+            # answered op must be durable already
+            checks["volatile_medium"] = (int(fs in durable.VOLATILE_FS), 0)
+            recovered, recover_s = crash_and_recover(medium, restart,
+                                                     rep.state)
+            checks.update(recovered)
+            say(f"recover_s: {recover_s:.3f} ({cfg['shards']} shards, "
+                f"{len(rep.state)} keys held by the reference)")
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)
         jax.monitoring.unregister_event_listener(compiles.event)
+        if medium is not None:
+            medium.stop()
+            shutil.rmtree(root, ignore_errors=True)
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
