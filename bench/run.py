@@ -46,6 +46,7 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import array  # noqa: E402
 import contextlib  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -62,7 +63,8 @@ from bench.traced import TracedRun  # noqa: E402
 
 sys.path.insert(0, str(spec.ROOT / "src"))
 
-# the program's spans the traced run reads, and its stacked apply program
+# the program's spans that idle time is attributed to (the readers see
+# every span), and its stacked apply program
 PROGRAM_SPANS = ("service.wave", "wave.compile", "wave.schedule",
                  "wave.dispatch", "wave.complete",
                  "executor.stacked_dispatch")
@@ -246,12 +248,12 @@ class WindowTrace:
         return self
 
     def collect(self) -> None:
-        """Keep the wave's program spans; drop its per-op events."""
+        """Keep every complete span the program emitted in the wave;
+        drop its per-op instants."""
         from repro.obs import get_tracer
         tracer = get_tracer()
         self.spans += [(e["ts"], e["dur"], e["name"])
-                       for e in tracer.events()
-                       if e.get("ph") == "X" and e["name"] in PROGRAM_SPANS]
+                       for e in tracer.events() if e.get("ph") == "X"]
         tracer.clear()
 
     def __exit__(self, *exc) -> bool:
@@ -329,6 +331,32 @@ def _percentile_ms(lat: array.array, q: float) -> Optional[float]:
     if not len(lat):
         return None
     return float(np.percentile(np.frombuffer(lat, "d"), q)) * 1e3
+
+
+def attributed(spans: List) -> List:
+    """The spans idle time is attributed to: ``PROGRAM_SPANS`` only, so
+    a span nested in one of them (``wave.snapshot`` in ``wave.compile``)
+    takes no idle time from it."""
+    return [s for s in spans if s[2] in PROGRAM_SPANS]
+
+
+def window_counters(svc) -> Dict[str, int]:
+    """The window's counts beside the six fixed ones: every ``int`` field
+    of the service's ``ServiceStats`` that starts at 0 (``round_cap``,
+    a setting, is not one) as ``service.<field>``, and every counter and
+    gauge of the program's metrics registry, summed over its labels, as
+    ``registry.<name>``.  ``reset_stats()`` zeroes both at the window's
+    start."""
+    from repro.obs import get_registry
+    stats = svc.stats
+    counts = {f"service.{f.name}": getattr(stats, f.name)
+              for f in dataclasses.fields(stats)
+              if f.default == 0 and type(getattr(stats, f.name)) is int}
+    for s in get_registry().series():
+        if s.kind in ("counter", "gauge"):
+            key = f"registry.{s.name}"
+            counts[key] = counts.get(key, 0) + s.value
+    return counts
 
 
 def _read_trace(log_dir: str, loop: ClosedLoop, spans: List,
@@ -439,6 +467,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         counters = dict(waves=waves, dispatches=dstats.dispatches,
                         ops_executed=stats.ops_executed,
                         shards=cfg["shards"], round_cap=cfg["round_cap"])
+        counters.update(window_counters(svc))
         retraces = dstats.traces
 
         loop.finish()
@@ -512,8 +541,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             f"p99 {_percentile_ms(latencies, 99)} ms, max "
             f"{_percentile_ms(latencies, 100)} ms")
     else:
-        dtrace = _read_trace(traced.log_dir, loop, traced.spans,
-                             APPLY_PROGRAM)
+        dtrace = _read_trace(traced.log_dir, loop,
+                             attributed(traced.spans), APPLY_PROGRAM)
         shutil.rmtree(traced.log_dir, ignore_errors=True)
         run = TracedRun(config=cfg, peaks=peaks, spans=traced.span_ms(),
                         counters=counters, device=dtrace)

@@ -139,3 +139,45 @@ def test_the_medium_is_the_longest_mount_prefix(tmp_path):
     assert durable.filesystem_type("/srv/ab/x", str(mounts)) == "xfs"
     assert durable.filesystem_type("/srv/abc", str(mounts)) == "ext4"
     assert durable.filesystem_type("/srv", str(mounts)) == "ext4"
+
+
+def test_a_traced_durable_run_hands_its_commit_spans_and_fences_to_readers(
+        tiny_durable_cell, medium, monkeypatch):
+    """What a durable cell's readers will read: the committer's registry
+    counters over the window, and its ``wal.commit_round`` spans."""
+    from bench import spec
+    fences = {}
+
+    def mark_window_start(svc):
+        reset = svc.reset_stats
+
+        def reset_stats():
+            fences["start"] = svc.durability_stats().fences
+            reset()
+        svc.reset_stats = reset_stats
+
+    finish = run.ClosedLoop.finish
+
+    def mark_window_end(loop, *a, **kw):
+        fences.setdefault("end", loop.svc.durability_stats().fences)
+        return finish(loop, *a, **kw)
+
+    monkeypatch.setattr(run.ClosedLoop, "finish", mark_window_end)
+    seen = {}
+    metric_reader = spec.metric_reader
+
+    def spy(name):
+        def read(traced):
+            seen["run"] = traced
+            return metric_reader(name)(traced)
+        return read
+    monkeypatch.setattr(spec, "metric_reader", spy)
+    r = run.run_cell(tiny_durable_cell, 2 ** 31 + 61, 1.0, True,
+                     peaks=PEAKS, t_start=time.perf_counter(),
+                     plant=mark_window_start)
+    assert r["correct"], r["checks"]
+    traced = seen["run"]
+    window_fences = fences["end"] - fences["start"]
+    assert window_fences > 0
+    assert traced.registry_total("fences") == window_fences
+    assert traced.spans["wal.commit_round"]
