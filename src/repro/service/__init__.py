@@ -29,7 +29,7 @@ See DESIGN.md Sec. 8 for the architecture and the cross-shard
 serialization argument; ``examples/kv_service.py`` is the walkthrough.
 """
 from .checkers import check_migration_crash_sweep
-from .executor import (DispatchStats, SerialShardExecutor,
+from .executor import (DispatchStats, RowBatch, SerialShardExecutor,
                        StackedKernelExecutor, build_rounds, execute_wave,
                        schedule_wave, select_executor)
 from .journal import (CrossShardJournal, MIG_COMPLETED, MIG_MIGRATING,
@@ -45,6 +45,7 @@ __all__ = [
     "BatchScheduler", "OpFuture", "ServiceError",
     "KVService", "KVFuture",
     "SerialShardExecutor", "StackedKernelExecutor", "DispatchStats",
+    "RowBatch",
     "build_rounds", "schedule_wave", "execute_wave", "select_executor",
     "CrossShardJournal",
     "MigrationLog", "MIG_MIGRATING", "MIG_ROUTED", "MIG_COMPLETED",

@@ -34,12 +34,13 @@ applied at the batching layer).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Hashable, List, Optional, Sequence, Set, Tuple
+from typing import (Dict, Hashable, List, Optional, Sequence, Set, Tuple,
+                    Union)
 
 import numpy as np
 
 from repro.obs import span
-from repro.pmwcas import (Backend, KernelBackend, MwCASOp,
+from repro.pmwcas import (Backend, KernelBackend, MwCASOp, ops_from_arrays,
                           ops_to_arrays, pmwcas_apply_stacked)
 
 
@@ -64,38 +65,115 @@ class DispatchStats:
         return dataclasses.asdict(self)
 
 
+class RowBatch:
+    """A shard's compiled CAS ops in array form, one row per entry.
+
+    Row ``i`` of ``addr``/``exp``/``des`` (``int32``/``uint32``/``uint32``
+    ``[n, K]``, every slot a real target: the
+    :func:`~repro.pmwcas.ops_to_arrays` wire format without padding) is
+    the CAS of ``entries[i]``, whose ``local`` names its row in the batch
+    the wave compiled (:meth:`take` keeps that name).  Iterating yields
+    the entries, so round formation and completion treat it as the queue
+    it replaces; the stacked executor copies the rows straight into its
+    padded arrays, and only the serial fallback builds
+    :class:`MwCASOp`\\ s from them.
+    """
+
+    __slots__ = ("entries", "addr", "exp", "des")
+
+    def __init__(self, entries: List, addr: np.ndarray, exp: np.ndarray,
+                 des: np.ndarray):
+        self.entries = entries
+        self.addr = addr
+        self.exp = exp
+        self.des = des
+
+    def __len__(self) -> int:
+        return len(self.entries)
+
+    def __iter__(self):
+        return iter(self.entries)
+
+    def take(self, pos: np.ndarray) -> "RowBatch":
+        """The entries at positions ``pos`` with their rows."""
+        entries = self.entries
+        return RowBatch([entries[i] for i in pos.tolist()], self.addr[pos],
+                        self.exp[pos], self.des[pos])
+
+    def to_ops(self) -> List[MwCASOp]:
+        return ops_from_arrays(self.addr, self.exp, self.des)
+
+
+# what an executor runs for one shard: MwCASOps, or their rows
+Round = Union[List[MwCASOp], RowBatch]
+
+
+def _row_rounds(batch: RowBatch, round_cap: int):
+    """:func:`build_rounds`'s rule over a :class:`RowBatch` of bucket
+    pairs: ``(scheduled, deferred-or-over positions, defers, overflows)``.
+
+    Each row must target one bucket's adjacent pair of words, every pair
+    starting at one parity (the hash map's writes in one generation), so
+    two rows share a target exactly when they share their first address
+    and the FIFO rule is a pass over first occurrences: the first row of
+    each address is scheduled while the round has room, and a later row
+    of the same address is deferred when that first one was scheduled
+    and overflows when it was not."""
+    lead = batch.addr[:, 0].astype(np.int64)
+    if batch.addr.shape[1] != 2 or (batch.addr[:, 1] != lead + 1).any() \
+            or ((lead - lead[:1]) % 2).any():
+        raise ValueError("round formation over rows needs each row to be "
+                         "one bucket's pair of words, at one parity")
+    _, first, inverse = np.unique(lead, return_index=True,
+                                  return_inverse=True)
+    head = first[inverse.reshape(-1)]
+    is_first = head == np.arange(lead.size)
+    sched = is_first & (np.cumsum(is_first) <= round_cap)
+    later = np.flatnonzero(~sched)
+    n_defer = int((~is_first & sched[head]).sum())
+    return np.flatnonzero(sched), later, n_defer, later.size - n_defer
+
+
 def build_rounds(queues: Dict[int, Sequence], round_cap: int
                  ) -> Tuple[Dict[int, list], Dict[int, list],
                             Dict[int, int], Dict[int, int]]:
     """Form one conflict-free round per shard from FIFO queues.
 
-    ``queues`` maps shard -> sequence of entries, each entry an object
-    with a ``local`` attribute (a shard-local :class:`MwCASOp`).
-    Returns ``(rounds, leftovers, defers, overflows)``:
-    ``rounds[s]`` the entries scheduled this round, ``leftovers[s]`` the
-    entries to retry next round (conflict-deferred or over ``round_cap``,
-    original order preserved), and the two defer counters per shard.
+    ``queues`` maps shard -> a :class:`RowBatch`, or a sequence of
+    entries each with a ``local`` attribute (a shard-local
+    :class:`MwCASOp`).  Returns ``(rounds, leftovers, defers, overflows)``:
+    ``rounds[s]`` the entries scheduled this round (a :class:`RowBatch`
+    for a row queue), ``leftovers[s]`` the entries to retry next round
+    (conflict-deferred or over ``round_cap``, original order preserved),
+    and the two defer counters per shard.  An entry that shares a target
+    with one already scheduled is deferred, whether or not the round is
+    full (conflict-defer wins the attribution).
     """
     rounds: Dict[int, list] = {}
     leftovers: Dict[int, list] = {}
     defers: Dict[int, int] = {}
     overflows: Dict[int, int] = {}
     for shard, queue in queues.items():
-        claimed: set = set()
-        sched, later = [], []
-        n_defer = n_over = 0
-        for entry in queue:
-            targets = set(entry.local.addrs)
-            if targets & claimed:
-                n_defer += 1           # conflict-defer wins the attribution
-                later.append(entry)
-            elif len(sched) >= round_cap:
-                n_over += 1
-                later.append(entry)
-            else:
-                claimed |= targets
-                sched.append(entry)
-        if sched:
+        if isinstance(queue, RowBatch):
+            pos, rest, n_defer, n_over = _row_rounds(queue, round_cap)
+            sched = queue.take(pos)
+            later = [queue.entries[i] for i in rest.tolist()]
+        else:
+            claimed: set = set()
+            sched, later = [], []
+            n_defer = n_over = 0
+            for entry in queue:
+                targets = set(entry.local.addrs)
+                if targets & claimed:
+                    n_defer += 1       # conflict-defer wins the attribution
+                    later.append(entry)
+                elif len(sched) >= round_cap:
+                    n_over += 1
+                    later.append(entry)
+                else:
+                    claimed |= targets
+                    sched.append(entry)
+        if len(sched):
             rounds[shard] = sched
         if later:
             leftovers[shard] = later
@@ -124,7 +202,8 @@ def execute_wave(executor, backends: Sequence[Backend],
     round/CAS accounting; returns ``{shard: [(entry, won)]}`` for the
     caller to complete futures / requeue losers from."""
     verdicts = executor.execute(
-        backends, {s: [p.local for p in entries]
+        backends, {s: (entries if isinstance(entries, RowBatch)
+                       else [p.local for p in entries])
                    for s, entries in rounds.items()})
     stats.dispatch = getattr(executor, "stats", None)
     out: Dict[int, List[Tuple[object, bool]]] = {}
@@ -150,9 +229,11 @@ class SerialShardExecutor:
         self.stats = DispatchStats()
 
     def execute(self, backends: Sequence[Backend],
-                rounds: Dict[int, List[MwCASOp]]) -> Dict[int, List[bool]]:
+                rounds: Dict[int, Round]) -> Dict[int, List[bool]]:
         out: Dict[int, List[bool]] = {}
         for shard, ops in rounds.items():
+            if isinstance(ops, RowBatch):
+                ops = ops.to_ops()
             with span("executor.serial_round", shard=shard, ops=len(ops)):
                 verdicts = backends[shard].execute(ops)
             out[shard] = [bool(r.success) for r in verdicts]
@@ -198,12 +279,12 @@ class StackedKernelExecutor:
         return (backend.n_words, backend.use_kernel)
 
     def execute(self, backends: Sequence[Backend],
-                rounds: Dict[int, List[MwCASOp]]) -> Dict[int, List[bool]]:
+                rounds: Dict[int, Round]) -> Dict[int, List[bool]]:
         import jax.numpy as jnp
         # group EVERY kernel shard (not just those with a round this
         # wave): group membership fixes the stacked S axis
         groups: Dict[Hashable, List[int]] = {}
-        rest: Dict[int, List[MwCASOp]] = {}
+        rest: Dict[int, Round] = {}
         for shard, b in enumerate(backends):
             if isinstance(b, KernelBackend):
                 groups.setdefault(self._group_key(b), []).append(shard)
@@ -226,7 +307,8 @@ class StackedKernelExecutor:
                 B = self.round_cap
             else:
                 B = 1 << (B - 1).bit_length()    # capless: pow2 bucket
-            K = max(op.k for s in active for op in rounds[s])
+            widths = {s: _round_width(rounds[s]) for s in active}
+            K = max(k for k, _cells in widths.values())
             K = 1 << (K - 1).bit_length()        # next power of two
             shape = (len(shards), B, K, n_words, use_kernel)
             if shape in self.shapes:
@@ -242,11 +324,16 @@ class StackedKernelExecutor:
             for i, s in enumerate(shards):
                 if s not in rounds:
                     continue
-                a, e, d = ops_to_arrays(rounds[s], K)
-                addr[i, :a.shape[0]] = a
-                exp[i, :a.shape[0]] = e
-                des[i, :a.shape[0]] = d
-            real_cells = sum(op.k for s in active for op in rounds[s])
+                r = rounds[s]
+                if isinstance(r, RowBatch):
+                    a, e, d = r.addr, r.exp, r.des
+                else:
+                    a, e, d = ops_to_arrays(r, K)
+                n, k = a.shape
+                addr[i, :n, :k] = a
+                exp[i, :n, :k] = e
+                des[i, :n, :k] = d
+            real_cells = sum(cells for _k, cells in widths.values())
             self.stats.bytes_padded += \
                 (len(shards) * B * K - real_cells) * 3 * 4
             with span("executor.stacked_dispatch", shards=len(shards),
@@ -269,6 +356,14 @@ class StackedKernelExecutor:
             out.update(self._serial.execute(backends, rest))
             self.stats.serial_rounds += len(rest)
         return out
+
+
+def _round_width(r: Round) -> Tuple[int, int]:
+    """(targets of the widest op, targets in all) of one shard round."""
+    if isinstance(r, RowBatch):
+        return r.addr.shape[1], r.addr.size
+    ks = [op.k for op in r]
+    return max(ks), sum(ks)
 
 
 def select_executor(backends: Sequence[Backend], stack_kernel: bool = True,

@@ -20,6 +20,7 @@ tree shards run the split protocol between waves, exactly like
 """
 from __future__ import annotations
 
+import operator
 import pathlib
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Union
@@ -27,13 +28,13 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 from repro import PMemPool
 from repro.obs import (flush_reason, instant, op_tracing, reset_metrics,
                        span, tracing_enabled)
-from repro.pmwcas import Backend, MwCASOp, make_backend
+from repro.pmwcas import Backend, KernelBackend, MwCASOp, make_backend
 from repro.structures import (BzTreeIndex, DELETE, EXHAUSTED, FULL, HashMap,
                               INSERT, KVOp, NeedsResize, NeedsSplit, OK,
                               OutOfRegions, SCAN, StructResult)
 
-from .executor import DispatchStats, execute_wave, schedule_wave, \
-    select_executor
+from .executor import DispatchStats, RowBatch, execute_wave, \
+    schedule_wave, select_executor
 from .journal import MIG_MIGRATING, MIG_ROUTED, MigrationLog
 from .router import ShardRouter
 from .stats import ServiceStats, collect_durability, fresh_stats
@@ -72,6 +73,11 @@ class KVFuture:
         return f"KVFuture(client={self.client}, shard={self.shard}, {state})"
 
 
+_OP = operator.attrgetter("future.op")
+_ATTEMPTS = operator.attrgetter("attempts")
+_KIND = operator.attrgetter("kind")
+
+
 class _PendingKV:
     """Queue entry: future + the op compiled for the CURRENT wave.
 
@@ -84,7 +90,9 @@ class _PendingKV:
 
     def __init__(self, future: KVFuture):
         self.future = future
-        self.local: Optional[MwCASOp] = None      # set per wave
+        # set per wave: the compiled MwCASOp, or the op's row in its
+        # shard's RowBatch
+        self.local: Union[MwCASOp, int, None] = None
         self.attempts = 0
 
 
@@ -471,7 +479,8 @@ class KVService:
         """Compile shard ``s``'s queue against one snapshot.  Immediate
         results complete; split/resize requests run the structure's grow
         protocol (ops recompile next wave); CAS-compiled ops return for
-        round formation."""
+        round formation: a :class:`RowBatch` from the array pass, else a
+        list whose entries each hold their :class:`MwCASOp`."""
         struct = self.structs[s]
         if getattr(struct, "hdr", 0) and struct.migrating:
             # an in-flight directory doubling pumps a chunk per wave
@@ -480,16 +489,81 @@ class KVService:
         with span("wave.snapshot", shard=s) as sp:
             snap = struct.snapshot()
         self.stats.snapshot_ns += sp.dur_ns
-        timed, clock = tracing_enabled(), time.perf_counter_ns
+        timed = tracing_enabled()
+        queue = self._queues[s]
+        compile = (self._compile_rows
+                   if self._compiles_rows(s, queue, snap)
+                   else self._compile_ops)
+        ready, answered, resizes, splits = compile(s, queue, snap, timed)
+        done = self._finish_all(answered, timed)
+        self._queues[s] = []
+        later: List[_PendingKV] = []
+        if resizes:
+            # publish the doubling decision; the waiters recompile next
+            # wave against the split-brain table (room is immediate: a
+            # fresh generation has twice the buckets)
+            with flush_reason("structures", "doubling_swing"):
+                began = struct.begin_resize()
+            if began:
+                for pending in resizes:
+                    pending.attempts += 1
+                later.extend(resizes)
+            else:
+                done += self._finish_all(
+                    [(p, FULL, None) for p in resizes], timed)
+        if splits:
+            # grow first; this wave's compiled ops would mostly lose
+            # (the split freezes their leaf's meta), so everything on
+            # this shard recompiles next wave — BzTreeIndex.apply's rule
+            for leaf_base, waiters in sorted(splits.items()):
+                try:
+                    grew = self.structs[s].ensure_room(leaf_base)
+                except OutOfRegions:
+                    grew = False
+                    self.stats.shards[s].out_of_regions += 1
+                if grew:
+                    for pending in waiters:
+                        pending.attempts += 1
+                    later.extend(waiters)
+                else:
+                    done += self._finish_all(
+                        [(p, FULL, None) for p in waiters], timed)
+            self._requeue(s, ready + later)
+            return [], done
+        self._requeue(s, later)
+        return ready, done
+
+    def _compiles_rows(self, s: int, queue: List[_PendingKV],
+                       snap) -> bool:
+        """Whether shard ``s`` compiles this wave's queue in one array
+        pass (:meth:`HashMap.compile_batch`): a hash map that keeps
+        ``HashMap``'s own ``compile_op`` (a subclass's, or one set on the
+        instance, sees every op on the per-op path), on a kernel backend,
+        with no doubling in flight, and a queue with no SCAN and no op
+        past ``max_op_rounds``."""
+        struct = self.structs[s]
+        return (isinstance(struct, HashMap)
+                and type(struct).compile_op is HashMap.compile_op
+                and "compile_op" not in vars(struct)
+                and isinstance(self.backends[s], KernelBackend)
+                and not struct.gen_state(snap)[1]
+                and max(map(_ATTEMPTS, queue), default=0)
+                <= self.max_op_rounds
+                and SCAN not in map(_KIND, map(_OP, queue)))
+
+    def _compile_ops(self, s: int, queue: List[_PendingKV], snap,
+                     timed: bool):
+        """The per-op path: ``compile_op`` on each queued op.  Returns
+        ``(ready, answered, resizes, splits)``."""
+        struct = self.structs[s]
+        clock = time.perf_counter_ns
         compile_ns = exhausted = 0
         ready: List[_PendingKV] = []
-        later: List[_PendingKV] = []
         # (pending, status, value): answered here, completed once the
         # whole queue is compiled
         answered: List[tuple] = []
         splits: Dict[int, List[_PendingKV]] = {}
         resizes: List[_PendingKV] = []
-        queue = self._queues[s]
         for pending in queue:
             fut = pending.future
             if pending.attempts > self.max_op_rounds:
@@ -525,42 +599,32 @@ class KVService:
                 ready.append(pending)
         self.stats.ops_compiled += len(queue) - exhausted
         self.stats.compile_ns += compile_ns
-        done = self._finish_all(answered, timed)
-        self._queues[s] = []
-        if resizes:
-            # publish the doubling decision; the waiters recompile next
-            # wave against the split-brain table (room is immediate: a
-            # fresh generation has twice the buckets)
-            with flush_reason("structures", "doubling_swing"):
-                began = struct.begin_resize()
-            if began:
-                for pending in resizes:
-                    pending.attempts += 1
-                later.extend(resizes)
-            else:
-                done += self._finish_all(
-                    [(p, FULL, None) for p in resizes], timed)
-        if splits:
-            # grow first; this wave's compiled ops would mostly lose
-            # (the split freezes their leaf's meta), so everything on
-            # this shard recompiles next wave — BzTreeIndex.apply's rule
-            for leaf_base, waiters in sorted(splits.items()):
-                try:
-                    grew = self.structs[s].ensure_room(leaf_base)
-                except OutOfRegions:
-                    grew = False
-                    self.stats.shards[s].out_of_regions += 1
-                if grew:
-                    for pending in waiters:
-                        pending.attempts += 1
-                    later.extend(waiters)
-                else:
-                    done += self._finish_all(
-                        [(p, FULL, None) for p in waiters], timed)
-            self._requeue(s, ready + later)
-            return [], done
-        self._requeue(s, later)
-        return ready, done
+        return ready, answered, resizes, splits
+
+    def _compile_rows(self, s: int, queue: List[_PendingKV], snap,
+                      timed: bool):
+        """The array pass: the whole queue in one
+        :meth:`HashMap.compile_batch`, its writes handed on as one
+        :class:`RowBatch` in queue order (each entry's ``local`` its row).
+        ``compile_ns`` times the whole pass.  Returns ``(ready, answered,
+        resizes, splits)``."""
+        t0 = time.perf_counter_ns() if timed else 0
+        compiled = self.structs[s].compile_batch(list(map(_OP, queue)),
+                                                 snap)
+        pick = queue.__getitem__
+        answered = list(zip(map(pick, compiled.answered.tolist()),
+                            compiled.status, compiled.value))
+        ready = list(map(pick, compiled.rows.tolist()))
+        for row, pending in enumerate(ready):
+            pending.local = row
+        resizes = list(map(pick, compiled.resize.tolist()))
+        rows = RowBatch(ready, compiled.addr, compiled.exp, compiled.des)
+        stats = self.stats
+        stats.ops_compiled += len(queue)
+        stats.ops_compiled_batched += len(queue)
+        if timed:
+            stats.compile_ns += time.perf_counter_ns() - t0
+        return rows, answered, resizes, {}
 
     def _requeue(self, s: int, entries: List[_PendingKV]) -> None:
         """Merge entries back into the shard queue in submission order

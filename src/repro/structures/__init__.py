@@ -42,9 +42,9 @@ from .differential import (StructDifferentialReport, conservative_verdicts,
                            run_struct_differential, shadow_batch,
                            winner_blocking_verdicts)
 from .freelist import DoubleFree, FreeListAllocator, OutOfRegions
-from .hashmap import (DELETE, EMPTY, EXHAUSTED, EXISTS, FULL, HashMap,
-                      INSERT, KVOp, MIG_BIT, NOT_FOUND, NeedsResize, OK,
-                      READ, RoundTrace, SCAN, StructResult, TOMBSTONE,
+from .hashmap import (CompiledBatch, DELETE, EMPTY, EXHAUSTED, EXISTS, FULL,
+                      HashMap, INSERT, KVOp, MIG_BIT, NOT_FOUND, NeedsResize,
+                      OK, READ, RoundTrace, SCAN, StructResult, TOMBSTONE,
                       TornStructure, UPDATE)
 from .workload import (LOAD, WorkloadSpec, WorkloadStats, YCSB_A, YCSB_B,
                        YCSB_C, YCSB_E, batches, client_streams,
@@ -54,6 +54,7 @@ from .workload import (LOAD, WorkloadSpec, WorkloadStats, YCSB_A, YCSB_B,
 __all__ = [
     # hash map
     "HashMap", "KVOp", "StructResult", "RoundTrace", "TornStructure",
+    "CompiledBatch",
     "NeedsResize", "EMPTY", "TOMBSTONE", "MIG_BIT",
     "READ", "INSERT", "UPDATE", "DELETE", "SCAN",
     "OK", "EXISTS", "NOT_FOUND", "FULL", "EXHAUSTED",

@@ -145,6 +145,76 @@ class RoundTrace:
     success: np.ndarray            # bool[B]
 
 
+_KIND_CODE = {kind: code for code, kind in enumerate(_KINDS)}
+# CompiledBatch's compile-time answers, by code
+_STATUS_OF_CODE = np.array([OK, NOT_FOUND, EXISTS, FULL], dtype=object)
+_HASH_MULT = 2654435761            # Knuth multiplicative (HashMap._home)
+
+
+@dataclasses.dataclass
+class CompiledBatch:
+    """:meth:`HashMap.compile_batch`'s result for a queue of ``n`` ops.
+
+    Every op lands in exactly one of three index arrays (queue positions,
+    ascending): ``answered`` (decided at compile time, with ``status``
+    and ``value`` as :meth:`HashMap.compile_op`'s ``StructResult``),
+    ``resize`` (``NeedsResize(gen)``), or ``rows`` (compiled to the CAS
+    whose targets are row ``i`` of ``addr``/``exp``/``des`` for
+    ``rows[i]``: key word then value word of one bucket).
+    """
+    answered: np.ndarray           # int64[a]
+    status: List[str]
+    value: List[Optional[int]]
+    resize: np.ndarray             # int64[r]
+    gen: int
+    rows: np.ndarray               # int64[m]
+    addr: np.ndarray               # int32[m, 2]
+    exp: np.ndarray                # uint32[m, 2]
+    des: np.ndarray                # uint32[m, 2]
+
+
+def _probe(key: np.ndarray, key_words: np.ndarray, cap: int
+           ) -> Tuple[np.ndarray, np.ndarray]:
+    """:meth:`HashMap._locate` for many keys at once: (bucket holding the
+    key, first writable bucket), -1 for none, over one generation's
+    ``cap`` key words.  The probe windows advance together, doubling in
+    width, until every key has met itself or an EMPTY, or has probed all
+    ``cap`` buckets; positions wrap at the array's end."""
+    n = key.size
+    home = ((key.astype(np.uint64) * np.uint64(_HASH_MULT))
+            % np.uint64(cap)).astype(np.int64)
+    found = np.full(n, -1, np.int64)
+    writable = np.full(n, -1, np.int64)
+    active = np.arange(n)
+    probed, width = 0, 8
+    while active.size and probed < cap:
+        w = min(width, cap - probed)
+        steps = np.arange(w)
+        b = home[active, None] + (probed + steps)
+        b[b >= cap] -= cap
+        kw = key_words[b]
+        hit = kw == key[active, None]
+        stop = hit | (kw == EMPTY)
+        stopped = stop.any(1)
+        first = stop.argmax(1)
+        # the first tombstone before the stop is the writable bucket
+        tomb = (kw == TOMBSTONE) & (steps < np.where(stopped, first, w)
+                                    [:, None])
+        r = np.flatnonzero(tomb.any(1) & (writable[active] < 0))
+        writable[active[r]] = b[r, tomb[r].argmax(1)]
+        r = np.flatnonzero(stopped)
+        at = b[r, first[r]]
+        met = hit[r, first[r]]
+        found[active[r[met]]] = at[met]
+        empty = active[r[~met]]
+        writable[empty] = np.where(writable[empty] < 0, at[~met],
+                                   writable[empty])
+        active = active[~stopped]
+        probed += w
+        width = min(2 * width, 64)
+    return found, writable
+
+
 class HashMap:
     """Open-addressing (linear probing, tombstone) map over a Backend.
 
@@ -221,7 +291,7 @@ class HashMap:
         return base + 2 + 2 * n_buckets * ((1 << (max_doublings + 1)) - 1)
 
     def _home(self, key: int, g: int = 0) -> int:
-        return (key * 2654435761) % self.cap(g)    # Knuth multiplicative
+        return (key * _HASH_MULT) % self.cap(g)
 
     # -- reads -----------------------------------------------------------------
     def snapshot(self) -> np.ndarray:
@@ -457,6 +527,67 @@ class HashMap:
         return MwCASOp([guard,
                         (self.key_addr(fo, g), op.key, TOMBSTONE),
                         (self.value_addr(fo, g), v_cur, 0)])
+
+    def compile_batch(self, ops: Sequence[KVOp], snap: np.ndarray
+                      ) -> CompiledBatch:
+        """Compile a whole queue of ops against one snapshot in numpy.
+
+        The array form of calling :meth:`compile_op` on each op: the same
+        answers, the same verdicts, and for each op that compiles to a
+        CAS the same targets as one row of ``addr``/``exp``/``des``, rows
+        in queue order.  Steady state only: SCAN ops and a snapshot with
+        a doubling in flight go through :meth:`compile_op`.
+        """
+        g, mig = self.gen_state(snap)
+        if mig:
+            raise ValueError("compile_batch: a doubling is in flight; "
+                             "compile against the split-brain table "
+                             "with compile_op")
+        n = len(ops)
+        kind = np.fromiter((_KIND_CODE[op.kind] for op in ops), np.int8, n)
+        if (kind == _KIND_CODE[SCAN]).any():
+            raise ValueError("compile_batch: SCAN compiles with compile_op")
+        key = np.fromiter((op.key for op in ops), np.int64, n)
+        value = np.fromiter((op.value for op in ops), np.int64, n)
+        off, cap = self.arr_off(g), self.cap(g)
+        table = snap[off:off + 2 * cap]
+        found, writable = _probe(key, table[0::2], cap)
+        hit = found >= 0
+        is_read, is_ins = kind == _KIND_CODE[READ], kind == _KIND_CODE[INSERT]
+        # answered at compile time, in queue order: reads, inserts that
+        # find their key live (EXISTS) or no writable bucket (FULL when
+        # no doubling is left), updates and deletes that miss
+        no_room = is_ins & ~hit & (writable < 0)
+        resize = no_room & (g < self.max_doublings)
+        row = ~is_read & (hit != is_ins) & ~no_room
+        answer = ~row & ~resize
+        idx = np.flatnonzero(answer)
+        code = np.where(is_read[idx], np.where(hit[idx], 0, 1),
+                        np.where(is_ins[idx], np.where(hit[idx], 2, 3), 1))
+        # an answered op that found its key is a read or an EXISTS insert
+        val = np.where(hit[idx], table[2 * found[idx] + 1], -1)
+        rows = np.flatnonzero(row)
+        ins = is_ins[rows]
+        bucket = np.where(ins, writable[rows], found[rows])
+        rkey, rval = key[rows], value[rows]
+        addr = np.empty((rows.size, 2), np.int32)
+        addr[:, 0] = self.base + off + 2 * bucket
+        addr[:, 1] = addr[:, 0] + 1
+        exp = np.empty((rows.size, 2), np.uint32)
+        des = np.empty((rows.size, 2), np.uint32)
+        # INSERT: (EMPTY/TOMB -> key, 0 -> value); UPDATE: the key word is
+        # a guard (key -> key), value old -> new; DELETE: (key -> TOMB,
+        # value -> 0)
+        exp[:, 0] = np.where(ins, table[2 * bucket], rkey)
+        exp[:, 1] = np.where(ins, 0, table[2 * bucket + 1])
+        is_del = kind[rows] == _KIND_CODE[DELETE]
+        des[:, 0] = np.where(is_del, TOMBSTONE, rkey)
+        des[:, 1] = np.where(is_del, 0, rval)
+        return CompiledBatch(
+            answered=idx, status=_STATUS_OF_CODE[code].tolist(),
+            value=[None if v < 0 else v for v in val.tolist()],
+            resize=np.flatnonzero(resize), gen=g,
+            rows=rows, addr=addr, exp=exp, des=des)
 
     # -- directory doubling ----------------------------------------------------
     def _record_round(self, batch: List[MwCASOp], owners: List[int],
