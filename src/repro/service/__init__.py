@@ -17,7 +17,7 @@ clients multiplex onto the kernel/durable substrates:
   so no crash can half-apply one).
 - :class:`StackedKernelExecutor` — kernel shards' rounds stacked into
   one ``jax.vmap``-ped ``pmwcas_apply`` dispatch: S rounds, one device
-  call.
+  call, for any S; every round travels as a :class:`RowBatch`.
 - :class:`KVService` — the structures front: per-shard
   :class:`repro.structures.HashMap` / ``BzTreeIndex`` partitions,
   logical :class:`repro.structures.KVOp` submissions compiled
@@ -31,7 +31,7 @@ serialization argument; ``examples/kv_service.py`` is the walkthrough.
 from .checkers import check_migration_crash_sweep
 from .executor import (DispatchStats, RowBatch, SerialShardExecutor,
                        StackedKernelExecutor, build_rounds, execute_wave,
-                       schedule_wave, select_executor)
+                       schedule_wave)
 from .journal import (CrossShardJournal, MIG_COMPLETED, MIG_MIGRATING,
                       MIG_ROUTED, MigrationLog)
 from .router import CROSS_SHARD, RoutedOp, ShardRouter
@@ -46,7 +46,7 @@ __all__ = [
     "KVService", "KVFuture",
     "SerialShardExecutor", "StackedKernelExecutor", "DispatchStats",
     "RowBatch",
-    "build_rounds", "schedule_wave", "execute_wave", "select_executor",
+    "build_rounds", "schedule_wave", "execute_wave",
     "CrossShardJournal",
     "MigrationLog", "MIG_MIGRATING", "MIG_ROUTED", "MIG_COMPLETED",
     "check_migration_crash_sweep",

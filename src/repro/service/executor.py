@@ -9,8 +9,10 @@ single device dispatch — the batched analogue of S cores retiring their
 CAS rounds in the same cycle, and the reason service throughput scales
 with shard count instead of paying one dispatch per shard.
 
-Shards whose backend is not stackable (durable, sim, or kernel shards
-with mismatched shapes/flags) fall back to per-shard ``execute`` calls.
+Every shard round is a :class:`RowBatch`, the compiled ops' rows.
+Kernel shards stack by table width and kernel flag, a lone kernel shard
+included; a shard whose backend is not a kernel (durable, sim) gets one
+``execute`` call per round, with :class:`MwCASOp`\\ s built from its rows.
 
 The stacked dispatch is CACHED, not just batched (DESIGN.md Sec. 9.2):
 every distinct ``[S, B, K]`` shape fed to the jitted dispatch pays an
@@ -34,8 +36,7 @@ applied at the batching layer).
 from __future__ import annotations
 
 import dataclasses
-from typing import (Dict, Hashable, List, Optional, Sequence, Set, Tuple,
-                    Union)
+from typing import Dict, Hashable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -66,17 +67,17 @@ class DispatchStats:
 
 
 class RowBatch:
-    """A shard's compiled CAS ops in array form, one row per entry.
+    """A shard's compiled CAS ops in array form, one row per entry: the
+    one form a shard round takes from compile to the device.
 
     Row ``i`` of ``addr``/``exp``/``des`` (``int32``/``uint32``/``uint32``
-    ``[n, K]``, every slot a real target: the
-    :func:`~repro.pmwcas.ops_to_arrays` wire format without padding) is
-    the CAS of ``entries[i]``, whose ``local`` names its row in the batch
-    the wave compiled (:meth:`take` keeps that name).  Iterating yields
-    the entries, so round formation and completion treat it as the queue
-    it replaces; the stacked executor copies the rows straight into its
-    padded arrays, and only the serial fallback builds
-    :class:`MwCASOp`\\ s from them.
+    ``[n, K]``, the :func:`~repro.pmwcas.ops_to_arrays` wire format: real
+    targets first, ``addr = -1`` padding after) is the CAS of
+    ``entries[i]``.  Iterating yields the entries, so round formation and
+    completion treat it as the queue it replaces; the stacked executor
+    copies the rows straight into its padded arrays, and only a
+    non-kernel backend's ``execute`` gets :class:`MwCASOp`\\ s, built
+    from the rows by :meth:`to_ops`.
     """
 
     __slots__ = ("entries", "addr", "exp", "des")
@@ -87,6 +88,16 @@ class RowBatch:
         self.addr = addr
         self.exp = exp
         self.des = des
+
+    @classmethod
+    def pack(cls, entries: List, ops: Sequence[MwCASOp]) -> "RowBatch":
+        """``entries`` with their compiled ``ops`` as rows, at the widest
+        op's K."""
+        if not ops:
+            return cls(entries, np.empty((0, 0), np.int32),
+                       np.empty((0, 0), np.uint32),
+                       np.empty((0, 0), np.uint32))
+        return cls(entries, *ops_to_arrays(ops))
 
     def __len__(self) -> int:
         return len(self.entries)
@@ -104,26 +115,24 @@ class RowBatch:
         return ops_from_arrays(self.addr, self.exp, self.des)
 
 
-# what an executor runs for one shard: MwCASOps, or their rows
-Round = Union[List[MwCASOp], RowBatch]
+def _bucket_pairs(addr: np.ndarray) -> bool:
+    """Whether every row is one bucket's adjacent pair of words, every
+    pair starting at one parity (the hash map's writes in one
+    generation): two such rows share a target exactly when they share
+    their first address."""
+    if addr.shape[1] != 2:
+        return False
+    lead = addr[:, 0].astype(np.int64)
+    return bool((addr[:, 1] == lead + 1).all()
+                and not ((lead - lead[:1]) % 2).any())
 
 
-def _row_rounds(batch: RowBatch, round_cap: int):
-    """:func:`build_rounds`'s rule over a :class:`RowBatch` of bucket
-    pairs: ``(scheduled, deferred-or-over positions, defers, overflows)``.
-
-    Each row must target one bucket's adjacent pair of words, every pair
-    starting at one parity (the hash map's writes in one generation), so
-    two rows share a target exactly when they share their first address
-    and the FIFO rule is a pass over first occurrences: the first row of
-    each address is scheduled while the round has room, and a later row
-    of the same address is deferred when that first one was scheduled
-    and overflows when it was not."""
-    lead = batch.addr[:, 0].astype(np.int64)
-    if batch.addr.shape[1] != 2 or (batch.addr[:, 1] != lead + 1).any() \
-            or ((lead - lead[:1]) % 2).any():
-        raise ValueError("round formation over rows needs each row to be "
-                         "one bucket's pair of words, at one parity")
+def _first_occurrence_round(addr: np.ndarray, round_cap: int):
+    """The FIFO rule over bucket-pair rows, as one pass over first
+    addresses: the first row of each address is scheduled while the
+    round has room, and a later row of the same address is deferred when
+    that first one was scheduled and overflows when it was not."""
+    lead = addr[:, 0].astype(np.int64)
     _, first, inverse = np.unique(lead, return_index=True,
                                   return_inverse=True)
     head = first[inverse.reshape(-1)]
@@ -134,56 +143,61 @@ def _row_rounds(batch: RowBatch, round_cap: int):
     return np.flatnonzero(sched), later, n_defer, later.size - n_defer
 
 
-def build_rounds(queues: Dict[int, Sequence], round_cap: int
-                 ) -> Tuple[Dict[int, list], Dict[int, list],
+def _claim_set_round(addr: np.ndarray, round_cap: int):
+    """The FIFO rule over any rows: each row's real (>= 0) addresses
+    against the round's claim set."""
+    claimed: set = set()
+    sched: List[int] = []
+    later: List[int] = []
+    n_defer = 0
+    for i, row in enumerate(addr.tolist()):
+        targets = {a for a in row if a >= 0}
+        if targets & claimed:
+            n_defer += 1           # conflict-defer wins the attribution
+            later.append(i)
+        elif len(sched) >= round_cap:
+            later.append(i)
+        else:
+            claimed |= targets
+            sched.append(i)
+    return (np.asarray(sched, np.int64), np.asarray(later, np.int64),
+            n_defer, len(later) - n_defer)
+
+
+def build_rounds(queues: Dict[int, RowBatch], round_cap: int
+                 ) -> Tuple[Dict[int, RowBatch], Dict[int, list],
                             Dict[int, int], Dict[int, int]]:
     """Form one conflict-free round per shard from FIFO queues.
 
-    ``queues`` maps shard -> a :class:`RowBatch`, or a sequence of
-    entries each with a ``local`` attribute (a shard-local
-    :class:`MwCASOp`).  Returns ``(rounds, leftovers, defers, overflows)``:
-    ``rounds[s]`` the entries scheduled this round (a :class:`RowBatch`
-    for a row queue), ``leftovers[s]`` the entries to retry next round
-    (conflict-deferred or over ``round_cap``, original order preserved),
-    and the two defer counters per shard.  An entry that shares a target
-    with one already scheduled is deferred, whether or not the round is
-    full (conflict-defer wins the attribution).
+    ``queues`` maps shard -> its :class:`RowBatch` in queue order.
+    Returns ``(rounds, leftovers, defers, overflows)``: ``rounds[s]``
+    the rows scheduled this round, ``leftovers[s]`` the entries to retry
+    next round (conflict-deferred or over ``round_cap``, original order
+    preserved), and the two defer counters per shard.  An entry that
+    shares a target with one already scheduled is deferred, whether or
+    not the round is full (conflict-defer wins the attribution).  Rows
+    that are all bucket pairs take one first-occurrence pass; any other
+    rows take the claim-set loop; both apply that one rule.
     """
-    rounds: Dict[int, list] = {}
+    rounds: Dict[int, RowBatch] = {}
     leftovers: Dict[int, list] = {}
     defers: Dict[int, int] = {}
     overflows: Dict[int, int] = {}
-    for shard, queue in queues.items():
-        if isinstance(queue, RowBatch):
-            pos, rest, n_defer, n_over = _row_rounds(queue, round_cap)
-            sched = queue.take(pos)
-            later = [queue.entries[i] for i in rest.tolist()]
-        else:
-            claimed: set = set()
-            sched, later = [], []
-            n_defer = n_over = 0
-            for entry in queue:
-                targets = set(entry.local.addrs)
-                if targets & claimed:
-                    n_defer += 1       # conflict-defer wins the attribution
-                    later.append(entry)
-                elif len(sched) >= round_cap:
-                    n_over += 1
-                    later.append(entry)
-                else:
-                    claimed |= targets
-                    sched.append(entry)
-        if len(sched):
-            rounds[shard] = sched
-        if later:
-            leftovers[shard] = later
+    for shard, batch in queues.items():
+        form = (_first_occurrence_round if _bucket_pairs(batch.addr)
+                else _claim_set_round)
+        pos, rest, n_defer, n_over = form(batch.addr, round_cap)
+        if pos.size:
+            rounds[shard] = batch.take(pos)
+        if rest.size:
+            leftovers[shard] = [batch.entries[i] for i in rest.tolist()]
         defers[shard] = n_defer
         overflows[shard] = n_over
     return rounds, leftovers, defers, overflows
 
 
-def schedule_wave(queues: Dict[int, Sequence], round_cap: int, stats
-                  ) -> Tuple[Dict[int, list], Dict[int, list]]:
+def schedule_wave(queues: Dict[int, RowBatch], round_cap: int, stats
+                  ) -> Tuple[Dict[int, RowBatch], Dict[int, list]]:
     """:func:`build_rounds` plus defer/overflow accounting into a
     :class:`~repro.service.ServiceStats` — the wave-formation step both
     the raw scheduler and the KV front run."""
@@ -196,15 +210,12 @@ def schedule_wave(queues: Dict[int, Sequence], round_cap: int, stats
 
 
 def execute_wave(executor, backends: Sequence[Backend],
-                 rounds: Dict[int, Sequence], stats
+                 rounds: Dict[int, RowBatch], stats
                  ) -> Dict[int, List[Tuple[object, bool]]]:
     """Run one wave of formed shard rounds and record the per-shard
     round/CAS accounting; returns ``{shard: [(entry, won)]}`` for the
     caller to complete futures / requeue losers from."""
-    verdicts = executor.execute(
-        backends, {s: (entries if isinstance(entries, RowBatch)
-                       else [p.local for p in entries])
-                   for s, entries in rounds.items()})
+    verdicts = executor.execute(backends, rounds)
     stats.dispatch = getattr(executor, "stats", None)
     out: Dict[int, List[Tuple[object, bool]]] = {}
     for s, entries in rounds.items():
@@ -221,7 +232,8 @@ def execute_wave(executor, backends: Sequence[Backend],
 
 
 class SerialShardExecutor:
-    """Reference engine: one ``backend.execute`` call per shard round."""
+    """Reference engine: one ``backend.execute`` call per shard round,
+    with the round's rows as :class:`MwCASOp`\\ s."""
 
     name = "serial"
 
@@ -229,22 +241,21 @@ class SerialShardExecutor:
         self.stats = DispatchStats()
 
     def execute(self, backends: Sequence[Backend],
-                rounds: Dict[int, Round]) -> Dict[int, List[bool]]:
+                rounds: Dict[int, RowBatch]) -> Dict[int, List[bool]]:
         out: Dict[int, List[bool]] = {}
-        for shard, ops in rounds.items():
-            if isinstance(ops, RowBatch):
-                ops = ops.to_ops()
-            with span("executor.serial_round", shard=shard, ops=len(ops)):
-                verdicts = backends[shard].execute(ops)
+        for shard, batch in rounds.items():
+            with span("executor.serial_round", shard=shard, ops=len(batch)):
+                verdicts = backends[shard].execute(batch.to_ops())
             out[shard] = [bool(r.success) for r in verdicts]
             self.stats.serial_rounds += 1
         return out
 
 
 class StackedKernelExecutor:
-    """Kernel shard rounds in ONE vmapped dispatch; serial fallback for
-    everything else.  ``last_stacked`` records how many shard rounds the
-    most recent call actually stacked (tests and benches read it).
+    """Kernel shard rounds in ONE vmapped dispatch per kernel group (a
+    lone kernel shard included); serial fallback for every other
+    backend.  ``last_stacked`` records how many shard rounds the most
+    recent call actually stacked (tests and benches read it).
 
     Every distinct stacked shape pays one XLA retrace, so the dispatch
     is pinned to SHAPE BUCKETS ``[S, B_bucket, K_bucket]``:
@@ -279,27 +290,23 @@ class StackedKernelExecutor:
         return (backend.n_words, backend.use_kernel)
 
     def execute(self, backends: Sequence[Backend],
-                rounds: Dict[int, Round]) -> Dict[int, List[bool]]:
+                rounds: Dict[int, RowBatch]) -> Dict[int, List[bool]]:
         import jax.numpy as jnp
         # group EVERY kernel shard (not just those with a round this
         # wave): group membership fixes the stacked S axis
         groups: Dict[Hashable, List[int]] = {}
-        rest: Dict[int, Round] = {}
+        rest: Dict[int, RowBatch] = {}
         for shard, b in enumerate(backends):
             if isinstance(b, KernelBackend):
                 groups.setdefault(self._group_key(b), []).append(shard)
-        for shard, ops in rounds.items():
+        for shard, batch in rounds.items():
             if not isinstance(backends[shard], KernelBackend):
-                rest[shard] = ops
+                rest[shard] = batch
         out: Dict[int, List[bool]] = {}
         self.last_stacked = 0
         for key, shards in groups.items():
             active = [s for s in shards if s in rounds]
             if not active:
-                continue
-            if len(shards) < 2:
-                # a lone kernel shard gains nothing from stacking
-                rest[shards[0]] = rounds[shards[0]]
                 continue
             n_words, use_kernel = key
             B = max(len(rounds[s]) for s in active)
@@ -325,14 +332,10 @@ class StackedKernelExecutor:
                 if s not in rounds:
                     continue
                 r = rounds[s]
-                if isinstance(r, RowBatch):
-                    a, e, d = r.addr, r.exp, r.des
-                else:
-                    a, e, d = ops_to_arrays(r, K)
-                n, k = a.shape
-                addr[i, :n, :k] = a
-                exp[i, :n, :k] = e
-                des[i, :n, :k] = d
+                n, k = len(r), widths[s][0]
+                addr[i, :n, :k] = r.addr[:, :k]
+                exp[i, :n, :k] = r.exp[:, :k]
+                des[i, :n, :k] = r.des[:, :k]
             real_cells = sum(cells for _k, cells in widths.values())
             self.stats.bytes_padded += \
                 (len(shards) * B * K - real_cells) * 3 * 4
@@ -358,19 +361,11 @@ class StackedKernelExecutor:
         return out
 
 
-def _round_width(r: Round) -> Tuple[int, int]:
-    """(targets of the widest op, targets in all) of one shard round."""
-    if isinstance(r, RowBatch):
-        return r.addr.shape[1], r.addr.size
-    ks = [op.k for op in r]
-    return max(ks), sum(ks)
-
-
-def select_executor(backends: Sequence[Backend], stack_kernel: bool = True,
-                    round_cap: Optional[int] = None):
-    """Stacked engine whenever >= 2 shards are kernel-backed; pass the
-    scheduler's ``round_cap`` so stacked shapes stay compile-stable."""
-    n_kernel = sum(isinstance(b, KernelBackend) for b in backends)
-    if stack_kernel and n_kernel >= 2:
-        return StackedKernelExecutor(round_cap)
-    return SerialShardExecutor()
+def _round_width(r: RowBatch) -> Tuple[int, int]:
+    """(targets of the widest op, targets in all) of one shard round:
+    its real (>= 0) addresses, which each row holds first."""
+    real = r.addr >= 0
+    cells = np.count_nonzero(real)
+    if cells == real.size:              # no padding: every column real
+        return real.shape[1], cells
+    return int(real.sum(1).max()), cells

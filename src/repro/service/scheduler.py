@@ -40,7 +40,8 @@ from typing import Dict, List, Optional, Sequence
 from repro.obs import instant, op_tracing, span
 from repro.pmwcas import Backend, MwCASOp, OpResult, Target
 
-from .executor import execute_wave, schedule_wave, select_executor
+from .executor import (RowBatch, StackedKernelExecutor, execute_wave,
+                       schedule_wave)
 from .journal import CrossShardJournal
 from .router import RoutedOp, ShardRouter
 from .stats import ServiceStats, collect_durability, fresh_stats
@@ -86,10 +87,6 @@ class _Pending:
     routed: RoutedOp
     future: OpFuture
 
-    @property
-    def local(self) -> MwCASOp:          # build_rounds reads .local
-        return self.routed.local
-
 
 class BatchScheduler:
     def __init__(self, backends: Sequence[Backend], router: ShardRouter, *,
@@ -119,8 +116,7 @@ class BatchScheduler:
         self.backends = list(backends)
         self.router = router
         self.round_cap = round_cap
-        self.executor = executor or select_executor(self.backends,
-                                                    round_cap=round_cap)
+        self.executor = executor or StackedKernelExecutor(round_cap)
         self.journal = journal
         self.journal_prune_every = journal_prune_every
         self.wal_prune_every = wal_prune_every
@@ -221,8 +217,10 @@ class BatchScheduler:
     # -- shard rounds ----------------------------------------------------------
     def _shard_rounds(self) -> int:
         with span("wave.schedule"):
+            # each queue's shard-local ops as rows, in queue order
             rounds, leftovers = schedule_wave(
-                {s: q for s, q in self._queues.items() if q},
+                {s: RowBatch.pack(q, [p.routed.local for p in q])
+                 for s, q in self._queues.items() if q},
                 self.round_cap, self.stats)
             for s in self._queues:
                 self._queues[s] = leftovers.get(s, [])

@@ -102,10 +102,11 @@ class ServiceStats:
     by_status: Dict[str, int] = dataclasses.field(default_factory=dict)
     # where a wave's host time goes (KVService): ops compiled (by
     # compile_op, or by the array pass HashMap.compile_batch, which
-    # ops_compiled_batched counts alone) and batches of answers completed
-    # (completed ÷ complete_batches is the ops per batch), always
-    # counted; and, while tracing is enabled only, the nanoseconds of the
-    # whole-table snapshots (the ``wave.snapshot`` spans), of compiling
+    # ops_compiled_batched counts alone; an op answered before compile,
+    # EXHAUSTED or a SCAN, counts in neither) and batches of answers
+    # completed (completed ÷ complete_batches is the ops per batch),
+    # always counted; and, while tracing is enabled only, the nanoseconds
+    # of the whole-table snapshots (the ``wave.snapshot`` spans), of compiling
     # (each compile_op call, or the whole array pass), and of completing
     # ops (``_finish_all``, and ``_release_held`` for acks released later)
     ops_compiled: int = 0

@@ -535,8 +535,10 @@ class HashMap:
         The array form of calling :meth:`compile_op` on each op: the same
         answers, the same verdicts, and for each op that compiles to a
         CAS the same targets as one row of ``addr``/``exp``/``des``, rows
-        in queue order.  Steady state only: SCAN ops and a snapshot with
-        a doubling in flight go through :meth:`compile_op`.
+        in queue order.  ``KVService`` compiles every hash-map shard's
+        wave with it, on any backend.  Steady state only: SCAN ops (the
+        service answers them before it compiles) and a snapshot with a
+        doubling in flight go through :meth:`compile_op`.
         """
         g, mig = self.gen_state(snap)
         if mig:

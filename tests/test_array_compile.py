@@ -6,12 +6,16 @@ batches it hands to round formation and the stacked dispatch.
   CAS targets, resize and full verdicts — over tombstones, probe runs
   that wrap the array's end, misses, repeated keys, full tables and the
   4- and 16-shard clustering of the multiplier the router shares;
-- ``build_rounds`` on rows forms the rounds it forms on ``MwCASOp``\\ s,
-  and the stacked executor ships the arrays ``ops_to_arrays`` would;
-- engagement: a stacked kernel hash-map service compiles every op in the
-  array pass, and each deployment it does not take (a doubling in
-  flight, durable shards, BzTree shards, a ``compile_op`` set on the
-  instance) compiles none there, with the same answers;
+- ``build_rounds`` forms the same rounds from bucket-pair rows in its
+  first-occurrence pass as in its claim-set loop, takes the claim-set
+  loop for any other rows (a doubling's guarded 3- and 5-word rows
+  among them), and the stacked executor ships the arrays
+  ``ops_to_arrays`` would;
+- engagement: a hash-map service compiles every op in the array pass,
+  on kernel and durable shards alike, SCANs and exhausted ops answered
+  before it; each deployment it does not take (a doubling in flight,
+  BzTree shards, a ``compile_op`` set on the instance) compiles none
+  there, with the same answers;
 - served equivalence: one seeded YCSB-A stream gives the same answers,
   table and defer counts on either path, and the array path builds no
   ``MwCASOp``.
@@ -29,6 +33,7 @@ from repro.structures import (DELETE, EXHAUSTED, EXISTS, FULL, HashMap,
                               INSERT, KVOp, NOT_FOUND, NeedsResize, OK, READ,
                               SCAN, StructResult, TOMBSTONE, UPDATE, YCSB_A,
                               compile_workload, key_shard)
+from repro.structures.hashmap import MIG_BIT
 
 
 def _verdicts_per_op(hm, ops, snap):
@@ -173,61 +178,118 @@ def test_compile_batch_leaves_scans_and_doublings_to_compile_op():
 
 
 # ---------------------------------------------------------------------------
-# round formation and the stacked arrays, rows against MwCASOps
+# round formation and the stacked arrays
 # ---------------------------------------------------------------------------
 
-class _Entry:
-    def __init__(self, local):
-        self.local = local
-
-
 def _row_and_op_queues(hm, ops, snap):
-    """The same compiled writes as a RowBatch and as a MwCASOp queue."""
+    """The same compiled writes as the array pass's rows and as the
+    rows packed from ``compile_op``'s MwCASOps (returned too); the
+    entries are the queue positions."""
     cb = hm.compile_batch(ops, snap)
-    rows = RowBatch([_Entry(r) for r in range(cb.rows.size)], cb.addr,
-                    cb.exp, cb.des)
-    per_op = [_Entry(hm.compile_op(ops[i], snap)) for i in cb.rows.tolist()]
-    assert all(isinstance(e.local, MwCASOp) for e in per_op)
-    return rows, per_op
+    entries = cb.rows.tolist()
+    per_op = [hm.compile_op(ops[i], snap) for i in entries]
+    assert all(isinstance(op, MwCASOp) for op in per_op)
+    return (RowBatch(entries, cb.addr, cb.exp, cb.des),
+            RowBatch.pack(list(entries), per_op), per_op)
+
+
+def _padded(rows):
+    """The same rows with one more column of padding: no longer bucket
+    pairs, so round formation takes its claim-set loop."""
+    pad = np.full((len(rows), 1), -1, np.int32)
+    zero = np.zeros((len(rows), 1), np.uint32)
+    return RowBatch(list(rows.entries), np.hstack([rows.addr, pad]),
+                    np.hstack([rows.exp, zero]), np.hstack([rows.des, zero]))
 
 
 @pytest.mark.parametrize("round_cap", [1, 3, 8, 64])
 @pytest.mark.parametrize("seed", range(3))
 def test_build_rounds_on_rows_matches_mwcas_ops(seed, round_cap):
+    """The first-occurrence pass over the array pass's pair rows forms
+    the rounds, leftovers and counts the claim-set loop forms over the
+    same rows, and the rows are those of ``compile_op``'s MwCASOps."""
     rng = np.random.default_rng(seed)
     hm, snap, present, universe = _table(rng, 32, fill=0.5, tomb=0.2)
     # few keys, many ops: repeated buckets, so conflict-defers
     ops = _queue(rng, present[:10], universe[:14], 60)
-    rows, per_op = _row_and_op_queues(hm, ops, snap)
+    rows, packed, _ops = _row_and_op_queues(hm, ops, snap)
+    assert np.array_equal(rows.addr, packed.addr)
+    assert np.array_equal(rows.exp, packed.exp)
+    assert np.array_equal(rows.des, packed.des)
     r1, l1, d1, o1 = build_rounds({0: rows, 1: rows}, round_cap)
-    r2, l2, d2, o2 = build_rounds({0: per_op, 1: per_op}, round_cap)
+    r2, l2, d2, o2 = build_rounds({0: _padded(rows), 1: _padded(rows)},
+                                  round_cap)
     for s in (0, 1):
-        assert isinstance(r1[s], RowBatch)
-        assert [e.local for e in r1[s]] == [per_op.index(e) for e in r2[s]]
-        assert [e.local for e in l1.get(s, [])] == \
-            [per_op.index(e) for e in l2.get(s, [])]
-        a, e, d = ops_to_arrays([x.local for x in r2[s]])
-        assert np.array_equal(r1[s].addr, a)
-        assert np.array_equal(r1[s].exp, e)
-        assert np.array_equal(r1[s].des, d)
+        assert r1[s].entries == r2[s].entries
+        assert l1.get(s, []) == l2.get(s, [])
+        assert np.array_equal(r1[s].addr, r2[s].addr[:, :2])
+        assert np.array_equal(r1[s].exp, r2[s].exp[:, :2])
+        assert np.array_equal(r1[s].des, r2[s].des[:, :2])
     assert d1 == d2 and o1 == o2
     assert d1[0] > 0 and (round_cap > 3 or o1[0] > 0)
 
 
-@pytest.mark.parametrize("addr", [
-    [[0, 1, 2]],                    # three targets
-    [[0, 2]],                       # not adjacent
-    [[0, 1], [3, 4]],               # pairs at two parities
-], ids=["three", "gap", "parity"])
-def test_build_rounds_refuses_rows_that_are_not_bucket_pairs(addr):
-    """The first-occurrence pass is exact only for bucket pairs; any
-    other row shape is refused, not scheduled by a wrong rule."""
+@pytest.mark.parametrize("addr,sched,later,defers", [
+    ([[0, 1, 2], [2, 3, 4], [5, 6, 7]], [0, 2], [1], 1),
+    ([[0, 2], [2, 4], [1, 3]], [0, 2], [1], 1),
+    ([[0, 1], [1, 2], [3, 4]], [0, 2], [1], 1),
+    ([[0, 1], [3, -1], [3, 4], [5, 6]], [0, 1, 3], [2], 1),
+], ids=["three", "gap", "parity", "padding"])
+def test_build_rounds_refuses_rows_that_are_not_bucket_pairs(
+        addr, sched, later, defers):
+    """Rows that are not one generation's bucket pairs (three targets,
+    a gap, two parities, padding) take the claim-set loop over their
+    real addresses; a first-occurrence pass would schedule every row of
+    these, as their first addresses differ."""
     addr = np.asarray(addr, np.int32)
-    rows = RowBatch([_Entry(i) for i in range(len(addr))], addr,
+    rows = RowBatch(list(range(len(addr))), addr,
                     np.zeros(addr.shape, np.uint32),
                     np.zeros(addr.shape, np.uint32))
-    with pytest.raises(ValueError, match="pair"):
-        build_rounds({0: rows}, 4)
+    rounds, leftovers, d, o = build_rounds({0: rows}, 4)
+    assert rounds[0].entries == sched
+    assert np.array_equal(rounds[0].addr, addr[sched])
+    assert leftovers[0] == later
+    assert d == {0: defers} and o == {0: 0}
+
+
+def test_a_doublings_guarded_rows_form_claim_set_rounds():
+    """While a doubling is in flight every write carries the generation
+    word as a guard: an insert is 3 words, an update of a key not yet
+    moved a 5-word move-on-write.  Packed at K = 5 with padding, the
+    rows share the guard, so each round takes one (the claim-set loop),
+    as ``HashMap.apply`` serializes guarded ops; the rest defer."""
+    hm = HashMap(KernelBackend(n_words=64, use_kernel=False), 8,
+                 max_doublings=1)
+    assert all(r.status == OK
+               for r in hm.apply([KVOp(INSERT, k, k) for k in range(1, 6)]))
+    assert hm.begin_resize()
+    snap = hm.snapshot()
+    assert snap[0] & MIG_BIT
+    ops = [KVOp(UPDATE, 2, 20), KVOp(INSERT, 40, 4), KVOp(DELETE, 3),
+           KVOp(UPDATE, 4, 44)]
+    compiled = [hm.compile_op(op, snap) for op in ops]
+    assert [op.k for op in compiled] == [5, 3, 3, 5]
+    rows = RowBatch.pack(list(range(4)), compiled)
+    assert rows.addr.shape == (4, 5) and (rows.addr[1:3, 3:] == -1).all()
+    assert (rows.addr[:, 0] == hm.gen_addr).all()
+    rounds, leftovers, d, o = build_rounds({0: rows}, 4)
+    assert rounds[0].entries == [0] and leftovers[0] == [1, 2, 3]
+    assert d == {0: 3} and o == {0: 0}
+    assert rounds[0].to_ops() == compiled[:1]
+    # served: a wave with a doubling in flight executes one op
+    svc = KVService(1, n_buckets=8, max_doublings=1, round_cap=4,
+                    use_kernel=False)
+    svc.apply([KVOp(INSERT, k, k) for k in range(1, 6)])
+    assert svc.structs[0].begin_resize()
+    svc.reset_stats()
+    futs = svc.submit_many(ops)
+    svc.step()
+    assert svc.structs[0].migrating
+    sh = svc.stats.shards[0]
+    assert (sh.rounds, sh.ops_executed, sh.defers) == (1, 1, 3)
+    svc.drain()
+    assert [f.result.status for f in futs] == [OK] * 4
+    assert svc.items() == {1: 1, 2: 20, 4: 44, 5: 5, 40: 4}
 
 
 @pytest.mark.parametrize("round_cap", [8, None])
@@ -251,10 +313,10 @@ def test_stacked_executor_ships_rows_as_ops_to_arrays(round_cap,
     for s in (0, 2):
         ops = _queue(np.random.default_rng(s), present, universe, 12,
                      kinds=(UPDATE, INSERT, DELETE))
-        rows, per_op = _row_and_op_queues(hm, ops, snap)
+        rows, _packed, per_op = _row_and_op_queues(hm, ops, snap)
         r1, _l, _d, _o = build_rounds({s: rows}, 8)
-        r2, _l, _d, _o = build_rounds({s: per_op}, 8)
-        queues[s] = (r1[s], [e.local for e in r2[s]])
+        sched = [per_op[rows.entries.index(e)] for e in r1[s]]
+        queues[s] = (r1[s], RowBatch.pack(r1[s].entries, sched), sched)
     results = []
     for side in (0, 1):
         backends = [KernelBackend(values=snap.astype(np.uint32),
@@ -272,7 +334,7 @@ def test_stacked_executor_ships_rows_as_ops_to_arrays(round_cap,
     addr, exp, des = rows_seen
     assert addr.shape == (3, 8, 2)
     assert (addr[1] == -1).all() and not exp[1].any() and not des[1].any()
-    for s, (_rows, ops) in queues.items():
+    for s, (_rows, _packed, ops) in queues.items():
         a, e, d = ops_to_arrays(ops)
         n = len(ops)
         assert np.array_equal(addr[s, :n], a) and (addr[s, n:] == -1).all()
@@ -314,13 +376,12 @@ def _hashmap_service(backend="kernel", n_shards=4, **kw):
 
 @pytest.mark.parametrize("n_shards", [1, 4])
 def test_array_pass_compiles_every_op_of_a_kernel_service(n_shards):
-    """Stacked (4 shards) or a lone kernel shard, whose serial fallback
-    builds the MwCASOps from the rows: the same answers and table as the
-    per-op path."""
+    """4 shards or a lone kernel shard, each stacked: the same answers
+    and table as the per-op path."""
     svc = _hashmap_service(n_shards=n_shards)
-    stacked = isinstance(svc.executor, StackedKernelExecutor)
-    assert stacked == (n_shards > 1)
     got, compiled, batched = _mixed_answers(svc, list(range(1, 41)))
+    assert svc.executor.stats.dispatches > 0
+    assert svc.executor.stats.serial_rounds == 0
     assert compiled >= 40 and batched == compiled
     ref = _hashmap_service(n_shards=n_shards)
     _wrap_compile_op(ref)
@@ -331,9 +392,9 @@ def test_array_pass_compiles_every_op_of_a_kernel_service(n_shards):
 
 @pytest.mark.parametrize("aside", ["scan", "exhausted"])
 def test_a_wave_with_a_scan_or_an_exhausted_op_compiles_per_op(aside):
-    """A shard queue that holds a SCAN, or an op past ``max_op_rounds``,
-    compiles per op that wave, with the answers of the per-op path; the
-    next wave without one takes the array pass again."""
+    """A SCAN, or an op past ``max_op_rounds``, is answered before the
+    compile, and the rest of its shard's queue compiles in the array
+    pass that same wave, with the answers of the per-op path."""
     keys = [k for k in range(1, 41) if key_shard(k, 4) == 0]
     ops = [KVOp(UPDATE, keys[0], 7), KVOp(UPDATE, keys[1], 8),
            KVOp(READ, keys[0])]
@@ -350,11 +411,11 @@ def test_a_wave_with_a_scan_or_an_exhausted_op_compiles_per_op(aside):
         if aside == "exhausted":
             svc._queues[0][1].attempts = 3       # past max_op_rounds
         svc.step()
-        # an exhausted op is answered, not compiled
-        assert svc.stats.ops_compiled == (4 if aside == "scan" else 3)
-        assert svc.stats.ops_compiled_batched == 0
+        # the aside op is answered, not compiled; the rest compile
+        assert svc.stats.ops_compiled == 3
+        assert svc.stats.ops_compiled_batched == (0 if per_op else 3)
         nxt = svc.apply([KVOp(READ, keys[1])])[0]
-        assert svc.stats.ops_compiled_batched == (0 if per_op else 1)
+        assert svc.stats.ops_compiled_batched == (0 if per_op else 4)
         answers.append([(f.result.status, f.result.value) for f in futs]
                        + [(nxt.status, nxt.value)])
     assert answers[0] == answers[1]
@@ -418,10 +479,19 @@ def test_array_pass_hands_a_full_generation_to_the_doubling():
 
 
 def test_array_pass_skips_durable_shards(tmp_path):
-    svc = _hashmap_service("durable", durable_root=tmp_path)
+    """Durable hash-map shards take the array pass too; their serial
+    rounds build the MwCASOps from the rows, with the answers and table
+    of the per-op path."""
+    svc = _hashmap_service("durable", durable_root=tmp_path / "rows")
     got, compiled, batched = _mixed_answers(svc, list(range(1, 41)))
-    assert compiled >= 40 and batched == 0
-    assert _mixed_answers(_hashmap_service(), list(range(1, 41)))[0] == got
+    assert compiled >= 40 and batched == compiled
+    assert svc.executor.stats.serial_rounds > 0
+    assert svc.executor.stats.dispatches == 0
+    ref = _hashmap_service("durable", durable_root=tmp_path / "ops")
+    _wrap_compile_op(ref)
+    want, compiled, batched = _mixed_answers(ref, list(range(1, 41)))
+    assert batched == 0 and compiled >= 40
+    assert got == want and svc.items() == ref.items()
 
 
 def test_array_pass_skips_bztree_shards():

@@ -18,7 +18,7 @@ from repro import Committer, MarkerCommitter, PMemPool, SimulatedCrash
 from repro.pmwcas import (DurabilityStats, DurableBackend, KernelBackend,
                           MwCASOp)
 from repro.service import (BatchScheduler, CrossShardJournal, KVService,
-                           ShardRouter, StackedKernelExecutor)
+                           RowBatch, ShardRouter, StackedKernelExecutor)
 from repro.structures import (INSERT, KVOp, UPDATE,
                               check_durable_crash_sweep)
 
@@ -214,7 +214,7 @@ def test_scheduler_round_is_one_fence_per_durable_shard(tmp_path):
 
 def _kernel_rounds(n_shards, words, wave, b_per_shard, k):
     """One wave of same-bucket rounds: b_per_shard ops of width k per
-    shard, fresh addresses per wave so every op wins."""
+    shard as rows, fresh addresses per wave so every op wins."""
     rounds = {}
     for s in range(n_shards):
         ops = []
@@ -222,7 +222,7 @@ def _kernel_rounds(n_shards, words, wave, b_per_shard, k):
             base = (wave * b_per_shard + i) * k
             ops.append(MwCASOp([((base + j) % words, 0, 1)
                                 for j in range(k)]).sorted())
-        rounds[s] = ops
+        rounds[s] = RowBatch.pack(ops, ops)
     return rounds
 
 
@@ -258,7 +258,8 @@ def test_stacked_dispatch_with_idle_shards_matches_serial():
               for _ in range(n_shards)]
     ex = StackedKernelExecutor(round_cap=4)
     rounds = {0: [MwCASOp([(1, 0, 5)])], 2: [MwCASOp([(3, 0, 7)])]}
-    out = ex.execute(stacked, rounds)
+    out = ex.execute(stacked, {s: RowBatch.pack(ops, ops)
+                               for s, ops in rounds.items()})
     assert set(out) == {0, 2} and out[0] == [True] and out[2] == [True]
     for s, ops in rounds.items():
         serial[s].execute(ops)
@@ -282,9 +283,30 @@ def test_kvservice_steady_state_waves_never_retrace():
     assert svc.stats.as_row()["traces"] == 0
 
 
-def test_serial_executor_counts_rounds():
-    svc = KVService(1, structure="hashmap", n_buckets=16, round_cap=4,
+def test_one_kernel_shard_stacks_without_retracing():
+    """A lone kernel shard takes the stacked dispatch at B = round_cap:
+    after the warm-up, waves of every round length from 1 to the cap
+    reuse its one compiled shape."""
+    svc = KVService(1, structure="hashmap", n_buckets=64, round_cap=8,
                     use_kernel=False)
+    svc.apply([KVOp(INSERT, k, k) for k in range(1, 41)])      # warmup
+    assert svc.executor.stats.traces == 1
+    svc.reset_stats()
+    lengths = []
+    for n in (1, 3, 8, 5, 2, 7):
+        svc.apply([KVOp(UPDATE, k, 100 + k) for k in range(1, n + 1)])
+        lengths.append(svc.stats.shards[0].ops_executed - sum(lengths))
+    assert lengths == [1, 3, 8, 5, 2, 7]
+    d = svc.executor.stats
+    assert d.traces == 0 and d.hits == d.dispatches == 6
+    assert d.serial_rounds == 0
+    assert svc.executor.shapes == {(1, 8, 2, svc.words_per_shard, False)}
+
+
+def test_serial_executor_counts_rounds():
+    # a sim shard is not a kernel: its rounds run serially
+    svc = KVService(1, structure="hashmap", backend="sim", n_buckets=16,
+                    round_cap=4)
     svc.apply([KVOp(INSERT, k, k) for k in range(1, 9)])
     d = svc.stats.dispatch
     assert d is not None and d.serial_rounds > 0 and d.dispatches == 0

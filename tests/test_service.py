@@ -14,9 +14,9 @@ from repro import PMemPool, SimulatedCrash
 from repro.pmwcas import (DurableBackend, KernelBackend, MwCASOp, SimBackend,
                           make_backend, register_backend)
 from repro.service import (BatchScheduler, CROSS_SHARD, CrossShardJournal,
-                           KVService, SerialShardExecutor, ServiceError,
-                           ShardRouter, StackedKernelExecutor, build_rounds,
-                           select_executor)
+                           KVService, RowBatch, SerialShardExecutor,
+                           ServiceError, ShardRouter, StackedKernelExecutor,
+                           build_rounds)
 from repro.structures import (FULL, HashMap, INSERT, KVOp, OK, WorkloadSpec,
                               client_streams, compile_workload, interleave,
                               load_phase, partition_ops, replay_effects)
@@ -86,19 +86,18 @@ def test_router_key_routing_spreads_and_is_stable():
 # round formation: the conflict-defer rule
 # ---------------------------------------------------------------------------
 
-class _Entry:
-    def __init__(self, op):
-        self.local = op
-
-
 def test_build_rounds_defers_duplicate_targets_and_caps():
-    q = [_Entry(MwCASOp([(0, 0, 1)])), _Entry(MwCASOp([(1, 0, 1)])),
-         _Entry(MwCASOp([(0, 1, 2)])),          # dup target -> defer
-         _Entry(MwCASOp([(2, 0, 1)]))]
+    # K = 1 rows are not bucket pairs: the claim-set rule forms the round
+    ops = [MwCASOp([(0, 0, 1)]), MwCASOp([(1, 0, 1)]),
+           MwCASOp([(0, 1, 2)]),                # dup target -> defer
+           MwCASOp([(2, 0, 1)])]
+    q = RowBatch.pack(ops, ops)
+    assert q.addr.shape == (4, 1)
     rounds, leftovers, defers, overflows = build_rounds({0: q}, round_cap=2)
-    assert [e.local.addrs for e in rounds[0]] == [(0,), (1,)]
+    assert [e.addrs for e in rounds[0]] == [(0,), (1,)]
+    assert rounds[0].addr.tolist() == [[0], [1]]
     # the dup-target op deferred, the 4th op hit the cap
-    assert [e.local.addrs for e in leftovers[0]] == [(0,), (2,)]
+    assert [e.addrs for e in leftovers[0]] == [(0,), (2,)]
     assert defers[0] == 1 and overflows[0] == 1
 
 
@@ -205,11 +204,19 @@ def test_stacked_executor_matches_serial():
 
 
 def test_select_executor():
-    kb = [KernelBackend(n_words=4, use_kernel=False) for _ in range(3)]
-    assert isinstance(select_executor(kb), StackedKernelExecutor)
-    assert isinstance(select_executor(kb[:1]), SerialShardExecutor)
-    assert isinstance(select_executor([DurableBackend(), DurableBackend()]),
-                      SerialShardExecutor)
+    """One executor for every deployment: a lone kernel shard stacks,
+    and durable shards run serially behind it."""
+    kv = KVService(1, n_buckets=8, round_cap=4, use_kernel=False)
+    dur = KVService(2, backend=[DurableBackend(), DurableBackend()],
+                    n_buckets=8, round_cap=4)
+    for svc in (kv, dur):
+        assert isinstance(svc.executor, StackedKernelExecutor)
+        assert svc.apply([KVOp(INSERT, 5, 5)])[0].status == OK
+    assert kv.executor.stats.dispatches == 1
+    assert kv.executor.stats.serial_rounds == 0
+    assert kv.executor.shapes == {(1, 4, 2, 16, False)}
+    assert dur.executor.stats.dispatches == 0
+    assert dur.executor.stats.serial_rounds == 1
 
 
 # ---------------------------------------------------------------------------

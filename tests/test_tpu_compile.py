@@ -65,6 +65,17 @@ def test_stacked_dispatch_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_lone_shard_stacked_dispatch_compiles(one_chip):
+    """A 1-shard kernel deployment takes the same stacked dispatch at
+    S = 1, B = round_cap."""
+    S, W, B, K = 1, 2 ** 21, R, 2
+    text = _compiled_text(one_chip, pmwcas_apply_stacked,
+                          ((S, W), jnp.uint32), ((S, B, K), jnp.int32),
+                          ((S, B, K), jnp.uint32), ((S, B, K), jnp.uint32),
+                          use_kernel=True)
+    assert "tpu_custom_call" in text
+
+
 # B=64 K=4 is a serving-layer batch; the rest are the free-list shapes a
 # BzTree issues on a kernel backend: alloc([1]) and free() of one slot
 # (1,1), free() of a grant (1,n), a multi-request alloc (n,n), and the
